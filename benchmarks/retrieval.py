@@ -41,6 +41,7 @@ import time
 import numpy as np
 
 from repro import obs, serving
+from repro.launch.compile_cache import configure_compile_cache
 from repro.serving import index as serving_index
 from repro.serving import loadgen
 
@@ -375,6 +376,7 @@ def bench_load_sweep(x, q, *, k=10, qps_points=(100.0, 200.0, 400.0),
 
 
 def main(argv=None):
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sizes", type=int, nargs="+",
                     default=[2000, 8000, 100000])

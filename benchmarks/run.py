@@ -19,6 +19,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated subset (e.g. table1,fig9)")

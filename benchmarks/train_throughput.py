@@ -49,6 +49,7 @@ import numpy as np
 from repro import data, optim, training
 from repro.configs.speedyfeed_arch import make_sf_train_step
 from repro.core import speedyfeed_state
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.train import make_loader, small_speedyfeed_config
 
 
@@ -363,6 +364,7 @@ def run(epochs=2, repeats=2, seed=0, out=None, seg_len=32,
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=2)
     ap.add_argument("--repeats", type=int, default=2)

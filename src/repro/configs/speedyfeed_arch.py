@@ -13,6 +13,7 @@ Cells:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -37,6 +38,24 @@ PROD = core.make_config(
     n_news=1_204_224,   # Table 2's 1 202 576 row-padded to /4096 (sharding)
     gamma=20, beta=2e-3, encode_budget=4096,
     batch_users=1024, hist_len=100, merged_cap=8192, n_neg=4, remat=True)
+
+# One chip's data-parallel share of PROD: PROD's global batch spread over
+# a deployment of 8 chips (8 x v5e, each holding the replicated model and
+# the whole cache).  Every width, the vocab, K, S, L, news_dim and the
+# 1,204,224 cache rows stay as published; only the three per-step batch
+# sizes are cut, all by the same factor 8 (reduced: encode_budget 4096 ->
+# 512, batch_users 1024 -> 128, merged_cap 8192 -> 1024).  Why 8: PROD's
+# step needs 33.08 GB on one 15.75 GB v5e; compiled for a v5e with the
+# Pallas bus kernel, PROD/4 needs 5.04 GB arguments + 14.22 GB temps
+# (over the 85% bar of 13.39 GB) and PROD/8 needs 5.04 + 6.93 = 11.97 GB.
+# The arguments are the 3.70 GB fp32 cache plus 1.3 GB of params and Adam
+# moments, so no batch share can go below that.
+PROD_SHARE = 8
+PROD_1CHIP = dataclasses.replace(
+    PROD, batch_users=PROD.batch_users // PROD_SHARE,
+    merged_cap=PROD.merged_cap // PROD_SHARE,
+    cache=dataclasses.replace(
+        PROD.cache, encode_budget=PROD.cache.encode_budget // PROD_SHARE))
 
 CONV_BATCH = dict(users=512, hist=100, cands=2)  # conventional baseline
 

@@ -21,6 +21,12 @@ the bus *columns* of dk/dv flow back to the segment CLS rows through the
 wrapper's concat by plain autodiff — the kernel's custom_vjp boundary is
 (q, k, v, mask) -> o, see kernels.ops.bus_attention.
 
+Layout (what Mosaic accepts): the public arrays are [M, K, S, H, D]; the
+kernel runs on heads-major [M, K, H, S, D] copies with the segment and
+head axes squeezed out of the block, so every block's last two dims are
+the full (S, D) / (Sk, D) extents (Sk = 35 needs no padding then), and
+the mask travels as int32 [M, K, 1, Sk] (valid 1, masked 0).
+
 Grid: (M_blocks, K, H); block = one head of one segment for a block of
 news. The ops wrapper pads M up to a block_m multiple (padded rows carry
 an all-False mask and are sliced off) instead of degrading block_m.
@@ -38,9 +44,10 @@ NEG_INF = -1e30
 
 def _tile_softmax(q, k, mask, scale):
     """Masked scores + stable softmax for one [bm, S, Sk] tile; returns
-    (p, l, masked scores) with the exact arithmetic the forward uses."""
-    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,)))) * scale
-    s = jnp.where(mask[:, None, :], s, NEG_INF)          # [bm, S, Sk]
+    (p, l, m) with the exact arithmetic the forward uses."""
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask != 0, s, NEG_INF)                 # [bm, S, Sk]
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
@@ -48,88 +55,107 @@ def _tile_softmax(q, k, mask, scale):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, scale: float):
-    # blocks: q [bm, 1, S, 1, D]; k/v [bm, 1, Sk, 1, D]; mask [bm, 1, Sk]
-    q = q_ref[:, 0, :, 0, :].astype(jnp.float32)         # [bm, S, D]
-    k = k_ref[:, 0, :, 0, :].astype(jnp.float32)         # [bm, Sk, D]
-    v = v_ref[:, 0, :, 0, :].astype(jnp.float32)
-    mask = mask_ref[:, 0, :]                             # [bm, Sk] bool
-    p, l, _ = _tile_softmax(q, k, mask, scale)
-    o = jax.lax.dot_general(p / l, v, (((2,), (1,)), ((0,), (0,))))
-    o_ref[:, 0, :, 0, :] = o.astype(o_ref.dtype)
+    # blocks: q [bm, S, D]; k/v [bm, Sk, D]; mask [bm, 1, Sk] int32
+    q = q_ref[...].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    p, l, _ = _tile_softmax(q, k, mask_ref[...], scale)
+    o = jax.lax.dot_general(p / l, v, (((2,), (1,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32)
+    o_ref[...] = o.astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, dq_ref, dk_ref,
                 dv_ref, *, scale: float):
-    q = q_ref[:, 0, :, 0, :].astype(jnp.float32)         # [bm, S, D]
-    k = k_ref[:, 0, :, 0, :].astype(jnp.float32)         # [bm, Sk, D]
-    v = v_ref[:, 0, :, 0, :].astype(jnp.float32)
-    mask = mask_ref[:, 0, :]
-    do = do_ref[:, 0, :, 0, :].astype(jnp.float32)       # [bm, S, D]
+    q = q_ref[...].astype(jnp.float32)                   # [bm, S, D]
+    k = k_ref[...].astype(jnp.float32)                   # [bm, Sk, D]
+    v = v_ref[...].astype(jnp.float32)
+    mask = mask_ref[...]
+    do = do_ref[...].astype(jnp.float32)                 # [bm, S, D]
     p, l, _ = _tile_softmax(q, k, mask, scale)
     p = p / l                                            # [bm, S, Sk]
-    dv = jax.lax.dot_general(p, do, (((1,), (1,)), ((0,), (0,))))
-    dp = jax.lax.dot_general(do, v, (((2,), (2,)), ((0,), (0,))))
+    dv = jax.lax.dot_general(p, do, (((1,), (1,)), ((0,), (0,))),
+                             preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(do, v, (((2,), (2,)), ((0,), (0,))),
+                             preferred_element_type=jnp.float32)
     delta = (p * dp).sum(axis=-1, keepdims=True)         # [bm, S, 1]
     # masked keys' scores came through jnp.where -> their ds is exactly 0
-    ds = jnp.where(mask[:, None, :], p * (dp - delta), 0.0) * scale
-    dq = jax.lax.dot_general(ds, k, (((2,), (1,)), ((0,), (0,))))
-    dk = jax.lax.dot_general(ds, q, (((1,), (1,)), ((0,), (0,))))
-    dq_ref[:, 0, :, 0, :] = dq.astype(dq_ref.dtype)
-    dk_ref[:, 0, :, 0, :] = dk.astype(dk_ref.dtype)
-    dv_ref[:, 0, :, 0, :] = dv.astype(dv_ref.dtype)
+    ds = jnp.where(mask != 0, p * (dp - delta), 0.0) * scale
+    dq = jax.lax.dot_general(ds, k, (((2,), (1,)), ((0,), (0,))),
+                             preferred_element_type=jnp.float32)
+    dk = jax.lax.dot_general(ds, q, (((1,), (1,)), ((0,), (0,))),
+                             preferred_element_type=jnp.float32)
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-def _specs(S, Sk, H, D, block_m):
-    q_spec = pl.BlockSpec((block_m, 1, S, 1, D),
-                          lambda m, kk, h: (m, kk, 0, h, 0))
-    kv_spec = pl.BlockSpec((block_m, 1, Sk, 1, D),
-                           lambda m, kk, h: (m, kk, 0, h, 0))
-    mask_spec = pl.BlockSpec((block_m, 1, Sk), lambda m, kk, h: (m, kk, 0))
+def _specs(S, Sk, D, block_m):
+    q_spec = pl.BlockSpec((block_m, None, None, S, D),
+                          lambda m, kk, h: (m, kk, h, 0, 0))
+    kv_spec = pl.BlockSpec((block_m, None, None, Sk, D),
+                           lambda m, kk, h: (m, kk, h, 0, 0))
+    mask_spec = pl.BlockSpec((block_m, None, 1, Sk),
+                             lambda m, kk, h: (m, kk, 0, 0))
     return q_spec, kv_spec, mask_spec
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
-def bus_attention(q, k, v, kv_mask, *, block_m: int = 8,
-                  interpret: bool = True):
-    """q: [M, K, S, H, D]; k/v: [M, K, Sk, H, D]; kv_mask: [M, K, Sk] ->
-    [M, K, S, H, D]. Sk = S + K (bus columns appended by the wrapper);
-    masked (padded) keys contribute nothing. M % block_m == 0 (the ops
-    wrapper pads odd merged-set sizes up and masks the tail)."""
+def _heads_major(x):
+    """[M, K, T, H, D] <-> [M, K, H, T, D]."""
+    return x.transpose(0, 1, 3, 2, 4)
+
+
+def _prepare(q, k, v, kv_mask, block_m):
+    """Heads-major kernel operands and the int32 [M, K, 1, Sk] mask."""
     M, K, S, H, D = q.shape
     Sk = k.shape[2]
     block_m = min(block_m, M)
     assert M % block_m == 0, "pad M to a block_m multiple (ops.bus_attention)"
-    q_spec, kv_spec, mask_spec = _specs(S, Sk, H, D, block_m)
+    ops = (_heads_major(q), _heads_major(k), _heads_major(v),
+           kv_mask.astype(jnp.int32)[:, :, None, :])
+    return ops, (M, K, S, H, D, Sk, block_m)
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
+def bus_attention(q, k, v, kv_mask, *, block_m: int = 8, interpret: bool):
+    """q: [M, K, S, H, D]; k/v: [M, K, Sk, H, D]; kv_mask: [M, K, Sk] ->
+    [M, K, S, H, D]. Sk = S + K (bus columns appended by the wrapper);
+    masked (padded) keys contribute nothing. M % block_m == 0 (the ops
+    wrapper pads odd merged-set sizes up and masks the tail).
+    ``interpret`` has no default: kernels.ops decides it per backend."""
+    operands, (M, K, S, H, D, Sk, block_m) = _prepare(
+        q, k, v, kv_mask, block_m)
+    q_spec, kv_spec, mask_spec = _specs(S, Sk, D, block_m)
     kernel = functools.partial(_fwd_kernel, scale=D ** -0.5)
-    return pl.pallas_call(
+    o = pl.pallas_call(
         kernel,
         grid=(M // block_m, K, H),
         in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((M, K, S, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, K, H, S, D), q.dtype),
         interpret=interpret,
-    )(q, k, v, kv_mask)
+    )(*operands)
+    return _heads_major(o)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def bus_attention_bwd(q, k, v, kv_mask, do, *, block_m: int = 8,
-                      interpret: bool = True):
+                      interpret: bool):
     """(dq, dk, dv) for one fused tile pass; mask gets no cotangent."""
-    M, K, S, H, D = q.shape
-    Sk = k.shape[2]
-    block_m = min(block_m, M)
-    assert M % block_m == 0
-    q_spec, kv_spec, mask_spec = _specs(S, Sk, H, D, block_m)
+    operands, (M, K, S, H, D, Sk, block_m) = _prepare(
+        q, k, v, kv_mask, block_m)
+    q_spec, kv_spec, mask_spec = _specs(S, Sk, D, block_m)
     kernel = functools.partial(_bwd_kernel, scale=D ** -0.5)
-    return pl.pallas_call(
+    dq, dk, dv = pl.pallas_call(
         kernel,
         grid=(M // block_m, K, H),
         in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec],
         out_specs=[q_spec, kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((M, K, S, H, D), q.dtype),
-            jax.ShapeDtypeStruct((M, K, Sk, H, D), k.dtype),
-            jax.ShapeDtypeStruct((M, K, Sk, H, D), v.dtype),
+            jax.ShapeDtypeStruct((M, K, H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((M, K, H, Sk, D), k.dtype),
+            jax.ShapeDtypeStruct((M, K, H, Sk, D), v.dtype),
         ],
         interpret=interpret,
-    )(q, k, v, kv_mask, do)
+    )(*operands, _heads_major(do))
+    return _heads_major(dq), _heads_major(dk), _heads_major(dv)

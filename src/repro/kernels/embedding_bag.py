@@ -34,7 +34,7 @@ def _kernel(idx_ref, w_ref, table_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def embedding_bag(table, idx, weights=None, *, interpret: bool = True):
+def embedding_bag(table, idx, weights=None, *, interpret: bool):
     """table: [V, d]; idx: [B, F, nnz] int32; weights: [B, F, nnz] or None.
 
     Returns [B, F, d] = sum_n weights[b,f,n] * table[idx[b,f,n]].

@@ -144,7 +144,7 @@ def _shapes(q, k, block_q, block_k, causal):
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention_fwd(q, k, v, *, causal: bool = True, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = True):
+                        block_k: int = 128, interpret: bool):
     """q: [B, Sq, Hq, D]; k/v: [B, Sk, Hkv, D] ->
     (out [B, Sq, Hq, D], lse [B, Hq, Sq] f32)."""
     (B, Sq, Sk, Hq, Hkv, D, G, block_q, block_k, n_q, n_k,
@@ -185,7 +185,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, block_q: int = 128,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool):
     """q: [B, Sq, Hq, D]; k/v: [B, Sk, Hkv, D] -> [B, Sq, Hq, D]."""
     return flash_attention_fwd(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret)[0]
@@ -280,7 +280,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                              "interpret"))
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool):
     """(dq, dk, dv) from saved residuals + upstream cotangent ``do``."""
     (B, Sq, Sk, Hq, Hkv, D, G, block_q, block_k, n_q, n_k,
      q_off) = _shapes(q, k, block_q, block_k, causal)

@@ -14,13 +14,14 @@ the kernels compose with ``jax.checkpoint``/``cfg.remat`` without a
 second recompute of anything quadratic. Inputs may be bf16; every kernel
 accumulates in f32 and returns gradients in the primal dtypes.
 
-Backend selection: on this container (CPU) every kernel runs in
-interpret mode — the kernel body executes with real Pallas semantics,
-the correctness-validation path; on TPU the same calls compile to
-Mosaic. ``resolve_attn_impl`` maps the configs' default ``"auto"`` to
-"pallas" exactly when the backend compiles it for real (TPU), so the
-training hot path picks the fused kernels up automatically on device
-while CPU test runs keep the fast XLA reference.
+Backend selection happens here and nowhere else: the raw kernels take
+``interpret`` as a required keyword, and ``_interpret()`` sets it from
+the backend — interpret mode on CPU (the kernel body executes with real
+Pallas semantics, the correctness-validation path), Mosaic on TPU.
+``resolve_attn_impl`` maps the configs' default ``"auto"`` to "pallas"
+exactly when the backend compiles it for real (TPU), so the training hot
+path picks the fused kernels up automatically on device while CPU test
+runs keep the fast XLA reference.
 """
 from __future__ import annotations
 
@@ -28,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import bus_attention as _bus
 from . import embedding_bag as _ebag
@@ -105,6 +107,27 @@ def _pad_rows(x, m_pad: int):
     return jnp.pad(x, ((0, m_pad),) + ((0, 0),) * (x.ndim - 1))
 
 
+def _data_mesh():
+    """The ambient mesh when it has a ``data`` axis, else None.  XLA cannot
+    partition a Mosaic call, so under a mesh the kernel runs per device
+    inside a shard_map over the news axis (the encode set is data
+    parallel); ``Trainer`` makes its mesh ambient while it traces."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "data" not in mesh.axis_names:
+        return None
+    return mesh
+
+
+def _per_data_shard(fn, mesh, n_out: int):
+    """``fn`` over row blocks of every operand on the mesh's data axis."""
+    if mesh is None:
+        return fn
+    spec = P("data")
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec,
+                         out_specs=spec if n_out == 1 else (spec,) * n_out,
+                         check_vma=False)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _bus_vjp(q, k, v, kv_mask, block_m, interpret):
     return _bus_vjp_fwd(q, k, v, kv_mask, block_m, interpret)[0]
@@ -112,12 +135,15 @@ def _bus_vjp(q, k, v, kv_mask, block_m, interpret):
 
 def _bus_vjp_fwd(q, k, v, kv_mask, block_m, interpret):
     M = q.shape[0]
-    m_pad = -M % min(block_m, M)
+    mesh = _data_mesh()
+    shards = mesh.shape["data"] if mesh is not None else 1
+    m_pad = -M % (min(block_m, M) * shards)
     if m_pad:      # padded rows: all-False mask, sliced off below
         q, k, v = (_pad_rows(t, m_pad) for t in (q, k, v))
         kv_mask = _pad_rows(kv_mask, m_pad)
-    o = _bus.bus_attention(q, k, v, kv_mask, block_m=block_m,
-                           interpret=interpret)
+    fwd = functools.partial(_bus.bus_attention, block_m=block_m,
+                            interpret=interpret)
+    o = _per_data_shard(fwd, mesh, 1)(q, k, v, kv_mask)
     return o[:M], (q, k, v, kv_mask)
 
 
@@ -126,8 +152,9 @@ def _bus_vjp_bwd(block_m, interpret, res, do):
     m_pad = q.shape[0] - do.shape[0]
     if m_pad:
         do = _pad_rows(do, m_pad)
-    dq, dk, dv = _bus.bus_attention_bwd(q, k, v, kv_mask, do,
-                                        block_m=block_m, interpret=interpret)
+    bwd = functools.partial(_bus.bus_attention_bwd, block_m=block_m,
+                            interpret=interpret)
+    dq, dk, dv = _per_data_shard(bwd, _data_mesh(), 3)(q, k, v, kv_mask, do)
     M = q.shape[0] - m_pad
     return dq[:M], dk[:M], dv[:M], None
 

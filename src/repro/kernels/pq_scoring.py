@@ -8,18 +8,16 @@ sum_m LUT[m, codes[n, m]] — a gather + segment accumulate per candidate.
 Two block-scoring variants, selected by ``variant``:
 
   "onehot"  TPU-native: the per-code gather is hostile to the VPU (random
-            lane indexing), so the kernel materializes the codes block as
-            a one-hot [block_n, M*K] matrix with broadcasted_iota compares
-            (pure VPU) and turns the whole gather+accumulate into ONE
-            [block_n, M*K] x [M*K] MXU contraction against the flattened
-            LUT.  K*M extra FLOPs per candidate, zero irregular memory
-            traffic — the MXU is idle during a scan anyway.
+            lane indexing), so for each sub-quantizer m the kernel builds
+            the one-hot [K, block_n] of its codes with a sublane-iota
+            compare (pure VPU) and accumulates LUT row m [1, K] times it
+            on the MXU, at HIGHEST precision so the f32 LUT entries come
+            through whole.  K*M extra FLOPs per candidate, zero irregular
+            memory traffic — the MXU is idle during a scan anyway.
   "gather"  direct LUT gather (codes offset into the flattened [M*K]
-            table) + sum over M.  In interpret mode the one-hot path's
-            [block_n, M*K] materialization is real host memory traffic,
-            so the gather is ~an order of magnitude cheaper there; on a
-            compiled backend it only pays off while M*K is small enough
-            that gather latency beats the contraction.
+            table) + sum over M.  In interpret mode the one-hot
+            materialization is real host memory traffic, so the gather is
+            ~an order of magnitude cheaper there; Mosaic cannot lower it.
   "auto"    gather when interpreting (CPU) — measured strictly faster at
             every M*K in BENCH_retrieval.json — else the MXU contraction.
 
@@ -34,6 +32,11 @@ Layouts:
                           -inf so a downstream top-k never selects them)
   out    [B, N]     f32
 
+Inside, the kernel sees what Mosaic tiles: codes transposed to
+[Bc, M, N] (candidates on the 128 lanes), validity and scores as
+[B, 1, N] rows, and block_n a whole number of lane tiles unless one
+block spans all of N.
+
 Grid: (B, N / block_n); the LUT block stays resident across the inner
 dimension while candidate blocks stream.
 """
@@ -45,49 +48,56 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _block_scores_onehot(lut_ref, codes_ref, *, n_codes: int):
-    lut = lut_ref[0].astype(jnp.float32)            # [M, K]
-    codes = codes_ref[0].astype(jnp.int32)          # [bn, M] (uint8 or i32)
-    bn, M = codes.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bn, M, n_codes), 2)
-    onehot = (iota == codes[:, :, None]).astype(jnp.float32)
-    # gather+accumulate as one MXU contraction against the flattened LUT
-    return jax.lax.dot_general(
-        onehot.reshape(bn, M * n_codes), lut.reshape(M * n_codes),
-        (((1,), (0,)), ((), ())))                   # [bn]
+LANE = 128
 
 
-def _block_scores_gather(lut_ref, codes_ref, *, n_codes: int):
-    lut = lut_ref[0].astype(jnp.float32)            # [M, K]
-    codes = codes_ref[0].astype(jnp.int32)          # [bn, M]
-    M = codes.shape[1]
-    offs = codes + (jnp.arange(M, dtype=jnp.int32) * n_codes)[None, :]
-    return jnp.take(lut.reshape(M * n_codes), offs,
-                    axis=0).sum(axis=1)             # [bn]
+def _row_scores_onehot(lut_ref, codes_ref, *, n_codes: int):
+    """[1, bn] scores of one sub-quantizer: the one-hot [K, bn] of its
+    codes (a VPU compare against a sublane iota) times its LUT row [1, K]
+    on the MXU."""
+    bn = codes_ref.shape[-1]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n_codes, bn), 0)
+    onehot = (iota == codes_ref[...].astype(jnp.int32)).astype(jnp.float32)
+    return jax.lax.dot_general(              # f32 LUT entries kept whole
+        lut_ref[...].astype(jnp.float32), onehot, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
-_SCORES = {"onehot": _block_scores_onehot, "gather": _block_scores_gather}
+def _row_scores_gather(lut_ref, codes_ref, *, n_codes: int):
+    """[1, bn] scores of one sub-quantizer by a direct LUT-row gather."""
+    del n_codes
+    return jnp.take(lut_ref[0].astype(jnp.float32),
+                    codes_ref[...].astype(jnp.int32), axis=0)
 
 
-def _kernel(lut_ref, codes_ref, o_ref, *, n_codes: int, variant: str):
-    o_ref[0, :] = _SCORES[variant](lut_ref, codes_ref,
-                                   n_codes=n_codes).astype(o_ref.dtype)
+_SCORES = {"onehot": _row_scores_onehot, "gather": _row_scores_gather}
 
 
-def _masked_kernel(lut_ref, codes_ref, valid_ref, o_ref, *, n_codes: int,
-                   variant: str):
-    scores = _SCORES[variant](lut_ref, codes_ref, n_codes=n_codes)
-    scores = jnp.where(valid_ref[0] != 0, scores, -jnp.inf)
-    o_ref[0, :] = scores.astype(o_ref.dtype)
+def _kernel(lut_ref, codes_ref, *rest, n_codes: int, variant: str):
+    """Grid step (b, n, m): add sub-quantizer m's scores into the output
+    block, which stays resident while m (the innermost axis) advances;
+    with a validity operand, mask the finished block at the last m."""
+    *valid_ref, o_ref = rest
+    m = pl.program_id(2)
+
+    @pl.when(m == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    o_ref[...] += _SCORES[variant](lut_ref, codes_ref, n_codes=n_codes)
+    if valid_ref:
+        @pl.when(m == pl.num_programs(2) - 1)
+        def _mask():
+            o_ref[...] = jnp.where(valid_ref[0][...] != 0, o_ref[...],
+                                   -jnp.inf)
 
 
 def _resolve_variant(variant: str, interpret: bool) -> str:
     if variant == "auto":
         # interpret mode executes the kernel body as real host ops, where
-        # the [block_n, M*K] one-hot materialization dominates; compiled
-        # Mosaic keeps the MXU contraction (the gather stays selectable
-        # explicitly for small-M*K experiments on device)
+        # the one-hot materialization dominates; compiled Mosaic keeps the
+        # MXU contraction (the gather is not a Mosaic-lowerable op)
         return "gather" if interpret else "onehot"
     if variant not in _SCORES:
         raise ValueError(f"unknown pq scan variant: {variant!r}")
@@ -97,53 +107,55 @@ def _resolve_variant(variant: str, interpret: bool) -> str:
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret",
                                              "variant"))
 def pq_lut_scores(lut, codes, valid=None, *, block_n: int = 128,
-                  interpret: bool = True, variant: str = "auto"):
+                  interpret: bool, variant: str = "auto"):
     """lut: [B, M, K] f32; codes: [Bc, N, M] uint8/int32 with Bc in {1, B}.
 
     Returns [B, N] f32: out[b, n] = sum_m lut[b, m, codes[min(b,Bc-1), n, m]].
     With valid [Bv, N] (Bv in {1, B}), out[b, n] = -inf where not
     valid[min(b,Bv-1), n] — the padded-CSR gather path scores fixed-width
     candidate blocks whose tail slots hold no entry.  ``variant`` picks
-    the block-scoring strategy (see module docstring).
+    the block-scoring strategy (see module docstring); ``interpret`` has
+    no default — kernels.ops decides it per backend.
     """
     variant = _resolve_variant(variant, interpret)
     B, M, K = lut.shape
     Bc, N, Mc = codes.shape
     assert Mc == M and Bc in (1, B), (codes.shape, lut.shape)
     block_n = min(block_n, N)
+    if block_n < N:         # a partial block must be whole lane tiles
+        block_n = min(-(-block_n // LANE) * LANE, N)
     pad = (-N) % block_n
-    if pad:
-        codes = jnp.pad(codes, ((0, 0), (0, pad), (0, 0)))
+    codes = jnp.pad(codes, ((0, 0), (0, pad), (0, 0)))
+    codes = codes.transpose(0, 2, 1)[:, :, None, :]          # [Bc, M, 1, Np]
     Np = N + pad
-    def _bcast(b_shared, *tail):
-        """Block index_map over (b, n), broadcasting b when shared; tail
-        pins any trailing block axes to 0."""
+
+    def _bcast(b_shared):
+        """Block index_map over (b, n, m), broadcasting b when shared."""
         if b_shared:
-            return lambda b, n: (0, n, *tail)
-        return lambda b, n: (b, n, *tail)
+            return lambda b, n, m: (0, m, 0, n)
+        return lambda b, n, m: (b, m, 0, n)
 
     in_specs = [
-        pl.BlockSpec((1, M, K), lambda b, n: (b, 0, 0)),
-        pl.BlockSpec((1, block_n, M), _bcast(Bc == 1, 0)),
+        pl.BlockSpec((None, None, 1, K), lambda b, n, m: (b, m, 0, 0)),
+        pl.BlockSpec((None, None, 1, block_n), _bcast(Bc == 1)),
     ]
-    operands = [lut, codes]
-    if valid is None:
-        kernel = functools.partial(_kernel, n_codes=K, variant=variant)
-    else:
+    operands = [lut[:, :, None, :], codes]
+    if valid is not None:
         Bv, Nv = valid.shape
         assert Nv == N and Bv in (1, B), (valid.shape, lut.shape)
-        valid = valid.astype(jnp.int32)
-        if pad:
-            valid = jnp.pad(valid, ((0, 0), (0, pad)))
-        in_specs.append(pl.BlockSpec((1, block_n), _bcast(Bv == 1)))
-        operands.append(valid)
-        kernel = functools.partial(_masked_kernel, n_codes=K, variant=variant)
+        valid = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, pad)))
+        in_specs.append(pl.BlockSpec(
+            (None, 1, block_n),
+            (lambda b, n, m: (0, 0, n)) if Bv == 1
+            else (lambda b, n, m: (b, 0, n))))
+        operands.append(valid[:, None, :])
     out = pl.pallas_call(
-        kernel,
-        grid=(B, Np // block_n),
+        functools.partial(_kernel, n_codes=K, variant=variant),
+        grid=(B, Np // block_n, M),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_n), lambda b, n: (b, n)),
-        out_shape=jax.ShapeDtypeStruct((B, Np), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, block_n),
+                               lambda b, n, m: (b, 0, n)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, Np), jnp.float32),
         interpret=interpret,
     )(*operands)
-    return out[:, :N]
+    return out[:, 0, :N]

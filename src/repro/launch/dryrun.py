@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding
 from repro import configs
 from repro.distributed import sharding as shx
 from . import roofline as rl
-from .mesh import make_production_mesh, set_mesh
+from .mesh import make_production_mesh
 
 
 def run_cell(cell, *, multi_pod: bool, verbose: bool = True):
@@ -37,7 +37,7 @@ def run_cell(cell, *, multi_pod: bool, verbose: bool = True):
         fn = cell.make_fn(mesh)
         args = cell.abstract_args(mesh)
         donate = cell.meta.get("donate_argnums", ())
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             # donation must match the runtime executable (train cells donate
             # the TrainState), or memory_analysis double-counts the state
             lowered = jax.jit(fn, donate_argnums=donate).lower(*args)

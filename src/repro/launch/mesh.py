@@ -11,11 +11,8 @@ import jax
 
 
 def _mk(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)   # older jax: Auto is the default
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -61,13 +58,3 @@ def parse_mesh_arg(spec: str | None):
             f"--mesh data={n} but only {have} device(s) visible; on CPU "
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={n}")
     return make_mesh_for(n)
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient, across jax versions:
-    jax.set_mesh (new) > jax.sharding.use_mesh > `with mesh:` (legacy)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh   # Mesh is itself a context manager on older jax

@@ -3,9 +3,9 @@
 Architecture (paper §5.1.4 production setup, on the repro.serving
 snapshot lifecycle):
   1. offline: encode the news corpus with the (Bus)LM news encoder, then
-     bootstrap the lifecycle — publish the corpus and run one full
-     ``IndexBuilder`` build (exact-flat, IVF-Flat, or IVF-PQ), installed
-     by atomic swap; full-precision embeddings stay in the service's
+     bootstrap the lifecycle — one full ``IndexBuilder`` build over the
+     corpus (exact-flat, IVF-Flat, or IVF-PQ), installed by atomic swap;
+     full-precision embeddings stay in the service's
      ``EmbeddingStore`` (host + device mirror) for user encoding and
      re-rank,
   2. online: every request goes through the continuous-batching
@@ -36,6 +36,7 @@ snapshots the whole registry (train + publish + serve, one process =
 one registry) to JSONL.
 
 Run: python -m repro.launch.serve --requests 64 --batch 16 \
+         [--config small|prod_1chip] \
          [--index ivf-pq|ivf-flat|exact] [--nprobe 8] [--k-prime 64] \
          [--rebuild-mid-loop] [--train-steps 6] [--metrics-out m.jsonl]
      python -m repro.launch.serve --open-loop --sweep 50 100 200 \
@@ -55,6 +56,10 @@ import numpy as np
 
 from repro import core, obs, serving
 from repro.resilience import FaultPlan, faults
+
+
+# recall@k the served path must reach against the exact-MIPS oracle
+RECALL_THRESHOLD = 0.7
 
 
 @dataclasses.dataclass
@@ -122,12 +127,14 @@ class Recommender:
         # fresh rows instead
         self.service_kw.setdefault("store_grow_chunk", 1024)
         self.service: serving.RetrievalService | None = None
+        # params are arguments, not closed-over constants: a closure bakes
+        # them into the executable (858 MB at PROD width, past the
+        # persistent compilation cache's entry limit)
         self._encode = jax.jit(
-            lambda t, f: core.buslm_encode(params["plm"], cfg.plm, t, f))
+            lambda p, t, f: core.buslm_encode(p, cfg.plm, t, f))
 
-        def user_encode(emb, hist, hist_mask):
-            theta = emb[hist]
-            return core.attentive_user(params["user"], theta, hist_mask)
+        def user_encode(p, emb, hist, hist_mask):
+            return core.attentive_user(p, emb[hist], hist_mask)
 
         self._user = jax.jit(user_encode)
 
@@ -135,6 +142,7 @@ class Recommender:
         """Offline bulk encode of the whole corpus (cells: encode_bulk)."""
         toks = self.store.tokens
         n = toks.shape[0]
+        plm = self.params["plm"]
         outs = []
         for i in range(0, n, chunk):
             t = jnp.asarray(toks[i:i + chunk])
@@ -143,16 +151,16 @@ class Recommender:
                 pad = chunk - t.shape[0]
                 t = jnp.pad(t, ((0, pad), (0, 0), (0, 0)))
                 f = jnp.pad(f, ((0, pad), (0, 0), (0, 0)))
-                outs.append(np.asarray(self._encode(t, f))[:-pad])
+                outs.append(np.asarray(self._encode(plm, t, f))[:-pad])
             else:
-                outs.append(np.asarray(self._encode(t, f)))
+                outs.append(np.asarray(self._encode(plm, t, f)))
         emb = np.concatenate(outs)
         emb[0] = 0.0              # pad news scores nothing
         return emb
 
     def build_index(self, *, chunk: int = 256, seed: int = 0):
-        """Encode the corpus, then bootstrap the snapshot lifecycle:
-        publish everything and install the first full build by swap."""
+        """Encode the corpus, then bootstrap the snapshot lifecycle: one
+        full build over the corpus, installed by swap."""
         emb = self._encode_corpus(chunk=chunk)
         n = emb.shape[0]
         nlist = max(4, min(64, n // 32))
@@ -164,17 +172,16 @@ class Recommender:
             ivf=serving.IVFConfig(nlist=nlist,
                                   nprobe=min(self.nprobe, nlist),
                                   metric=self.probe_metric),
-            seed=seed, devices=devices)
+            pq=serving.pq_config_for(emb.shape[1]), seed=seed,
+            devices=devices)
         self.service = serving.RetrievalService(
             builder, emb, k=self.k, k_prime=min(self.k_prime, n - 1),
-            compact_threshold=self.compact_threshold, auto_compact=False,
-            **self.service_kw)
+            compact_threshold=self.compact_threshold, **self.service_kw)
         self.service.store.attach_device_mirror()
-        # bootstrap = the lifecycle itself: publish corpus (row 0 is the
-        # pad news, never a candidate), one full build, one atomic swap
-        self.service.publish(np.arange(1, n), emb[1:])
-        self.service.rebuild(mode="full", block=True)
-        self.service.auto_compact = True
+        # the corpus (row 0 is the pad news, never a candidate) goes
+        # straight to the builder: the delta tier is for fresh news, and
+        # its backpressure cap refuses a corpus larger than the cap
+        self.service.swap(builder.build(np.arange(1, n), emb[1:]))
         return self.service
 
     def publish(self, ids, emb):
@@ -185,7 +192,8 @@ class Recommender:
 
     def encode_users(self, hist_batch: np.ndarray, mask: np.ndarray):
         """History -> user embedding, off the device-mirrored store."""
-        return np.asarray(self._user(self.service.store.device,
+        return np.asarray(self._user(self.params["user"],
+                                     self.service.store.device,
                                      jnp.asarray(hist_batch),
                                      jnp.asarray(mask)))
 
@@ -369,7 +377,14 @@ def measure_recall(rec: Recommender, histories, *, k: int, probe: int = 16):
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import configure_compile_cache
+    from repro.launch.train import (CONFIG_NAMES, make_loader,
+                                    speedyfeed_config, train_speedyfeed)
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
+    ap.add_argument("--config", default="small", choices=CONFIG_NAMES,
+                    help="speedyfeed config: small (CPU), prod_1chip (PROD "
+                         "widths at one chip's batch share), prod")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--k", type=int, default=10)
@@ -429,7 +444,8 @@ def main(argv=None):
                                 / "benchmarks" / "BENCH_retrieval.json"),
                     help="merge --open-loop sweep entries into this BENCH "
                          "json (pass an empty string to skip recording)")
-    ap.add_argument("--recall-threshold", type=float, default=0.7)
+    ap.add_argument("--recall-threshold", type=float,
+                    default=RECALL_THRESHOLD)
     ap.add_argument("--probe", type=int, default=16,
                     help="probe-subset size for the recall oracle")
     ap.add_argument("--train-steps", type=int, default=0,
@@ -458,9 +474,7 @@ def main(argv=None):
         obs.configure_reporter(path=args.metrics_out,
                                every_s=args.metrics_every or 10.0)
 
-    from repro.launch.train import (make_loader, small_speedyfeed_config,
-                                    train_speedyfeed)
-    cfg = small_speedyfeed_config()
+    cfg = speedyfeed_config(args.config)
     corpus, log, store, lcfg = make_loader(cfg)
     if args.train_steps > 0:
         res = train_speedyfeed(steps=args.train_steps, cfg=cfg,

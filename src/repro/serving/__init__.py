@@ -11,8 +11,8 @@ from .index import (PAD_ID, FlatIndex, IVFConfig, IVFFlatIndex, IVFPQIndex,
 from .online import (DeltaBuffer, DeltaOverflowError, DeltaView, hybrid_search,
                      ingest_from_cache, merge_topk_dedup)
 from .pq import (PQCodebook, PQConfig, fit_kmeans, kmeans, kmeans_minibatch,
-                 opq_train, pq_decode, pq_encode, pq_lut, pq_search, pq_train,
-                 sample_rows)
+                 opq_train, pq_config_for, pq_decode, pq_encode, pq_lut,
+                 pq_search, pq_train, sample_rows)
 from .scheduler import (DeadlineExceededError, RequestCancelledError,
                         RequestScheduler, ScheduledRequest, bucket_for,
                         pow2_buckets)

@@ -67,10 +67,17 @@ PQ_SCAN_VARIANT = "auto"
 ENCODE_CHUNK = 65536   # bulk PQ encode chunk: bounds the [chunk, M, K]
 #                        distance buffer at million-row adds
 
+# Precision of every full-precision score (flat scans, IVF-Flat, re-rank).
+# On TPU a float32 dot at DEFAULT precision may round its operands to
+# bf16; there the dense IVF-Flat scan and the per-probe gather of the
+# sharded path, two differently shaped dots, disagreed on 2 of 160 top-10
+# ids (v5e).
+FULL = jax.lax.Precision.HIGHEST
+
 # Module-level so every flat scan (FlatIndex, delta views, snapshots of any
 # vintage) shares ONE jit cache: a fresh buffer/snapshot at a shape seen
 # before hits the warm executable instead of re-jitting per instance.
-_flat_score = jax.jit(lambda q, v: q @ v.T)
+_flat_score = jax.jit(lambda q, v: jnp.dot(q, v.T, precision=FULL))
 
 
 def _next_cap(n: int) -> int:
@@ -184,11 +191,13 @@ def _search_flat_csr(q, cent_unit, cent_raw, list_ids, list_vecs, lens, *,
         # cells): score every cell once in one MXU/BLAS matmul and gather
         # only the probed [B, P, cap] score blocks — far cheaper than
         # duplicating cell payloads per query through a vector gather
-        all_s = jnp.einsum("bd,lcd->blc", q, list_vecs)     # [B, nlist, cap]
+        all_s = jnp.einsum("bd,lcd->blc", q, list_vecs,
+                           precision=FULL)                  # [B, nlist, cap]
         scores = jnp.take_along_axis(all_s, probes[:, :, None], axis=1)
     else:
         # sparse coverage (nlist >> B*nprobe): gather only probed payloads
-        scores = jnp.einsum("bd,bpcd->bpc", q, list_vecs[probes])
+        scores = jnp.einsum("bd,bpcd->bpc", q, list_vecs[probes],
+                            precision=FULL)
     return _masked_topk(scores.reshape(B, -1), cand_ids, valid, k)
 
 

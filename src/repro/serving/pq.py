@@ -51,6 +51,15 @@ class PQConfig:
                 f"got {self.n_codes}")
 
 
+def pq_config_for(dim: int) -> PQConfig:
+    """PQ sized to the embedding: 8 dimensions per sub-quantizer (at least
+    8 sub-quantizers) and the whole uint8 code range.  The fixed default
+    (8 x 32 codes, 40 bits a vector) is sized for small test embeddings;
+    at news_dim 768 it left recall@10 of the served path at 0.47 against
+    0.975 for 96 x 256 codes (2-layer full-width encoder, CPU)."""
+    return PQConfig(n_subvec=max(8, dim // 8), n_codes=256)
+
+
 class PQCodebook(NamedTuple):
     centers: jax.Array     # [M, K, d/M]
     rot: Any = None        # [d, d] orthogonal OPQ rotation; None = identity
@@ -99,11 +108,13 @@ def kmeans(key, x, k: int, iters: int = 15):
     """Lloyd's k-means (L2) on x [N, d] -> (centroids [k, d], assignment).
     Fully jittable/vmappable: fixed iteration count; empty clusters are
     reseeded from the farthest points of the largest cluster each step.
+    k-means++ seeded like the mini-batch path: uniformly drawn seeds left
+    partition quality (and IVF recall) to the luck of the PRNG stream —
+    recall@10 spread 0.87-0.98 across build seeds on the test corpus.
     Jitted at module level so repeated builds at one shape (the
     background-rebuild loop) reuse ONE warm executable."""
-    n = x.shape[0]
-    idx = jax.random.choice(key, n, (k,), replace=n < k)
-    cent = jax.lax.fori_loop(0, iters, lambda _, c: _lloyd_iter(x, c), x[idx])
+    cent = jax.lax.fori_loop(0, iters, lambda _, c: _lloyd_iter(x, c),
+                             _kmeanspp_init(key, x, k))
     return cent, _assign(x, cent)
 
 

@@ -41,7 +41,7 @@ import numpy as np
 from repro import obs
 from repro.resilience import faults
 
-from .index import PAD_ID, _topk_padded
+from .index import FULL, PAD_ID, _topk_padded
 from .online import DeltaBuffer, DeltaView, hybrid_search
 from .snapshot import IndexSnapshot
 from .store import EmbeddingStore
@@ -59,7 +59,7 @@ class BackpressureError(RuntimeError):
 
 @jax.jit
 def _rerank_scores(q, cand_vecs, valid):
-    s = jnp.einsum("bd,bcd->bc", q, cand_vecs)
+    s = jnp.einsum("bd,bcd->bc", q, cand_vecs, precision=FULL)
     return jnp.where(valid, s, -jnp.inf)
 
 

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .index import PAD_ID, _masked_topk, _probe_cells
+from .index import FULL, PAD_ID, _masked_topk, _probe_cells
 from .pq import PQCodebook, pq_lut
 from .snapshot import IndexSnapshot
 
@@ -91,7 +91,8 @@ def _search_flat_sharded(q, cent_unit, cent_raw, ids_s, vecs_s, lens_s, *,
     def per_shard(s, ids_r, vecs_r, lens_r):
         pv = shard_of == s
         lp, cand, valid = _shard_gather(ids_r, lens_r, local, pv, cap, B)
-        sc = jnp.einsum("bd,bpcd->bpc", q, vecs_r[lp]).reshape(B, -1)
+        sc = jnp.einsum("bd,bpcd->bpc", q, vecs_r[lp],
+                        precision=FULL).reshape(B, -1)
         return _masked_topk(sc, cand, valid, k)
 
     s_sc, s_ids = jax.vmap(per_shard)(jnp.arange(S), ids_s, vecs_s, lens_s)

@@ -15,6 +15,7 @@ to host only at the checkpoint cadence.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import threading
 import time
@@ -207,6 +208,8 @@ class TrainResult:
     wall_seconds: float
     metrics: dict
     compile_counts: dict = dataclasses.field(default_factory=dict)
+    # compiles observed on a bucket's later steps (recompiles; should be {})
+    warm_compiles: dict = dataclasses.field(default_factory=dict)
     bucket_steps: dict = dataclasses.field(default_factory=dict)
     host_stall_fraction: float = 0.0
     # final TrainState (device arrays) — lets a downstream launcher serve
@@ -254,6 +257,7 @@ class Trainer:
             self._step_jit = None
         self.state_shardings: TrainState | None = None
         self.compile_counts: dict = {}    # bucket -> backend compiles
+        self.warm_compiles: dict = {}     # bucket -> compiles after its 1st
         self.bucket_steps: dict = {}      # bucket -> steps run
         self.monitor: StepTimeMonitor | None = None   # set by fit()
         self.last_state: TrainState | None = None     # final state of fit()
@@ -340,15 +344,38 @@ class Trainer:
         donated to the executable) — use only the returned state."""
         if self._step_jit is None:            # sharded path, first step
             state = self._build_mesh_jit(state, batch)
-        if bucket is not None and bucket not in self.compile_counts:
-            with CompileCounter() as cc:
+        if bucket is None:
+            with self._ambient_mesh():
+                return self._step_jit(state, batch)
+        if bucket not in self.compile_counts:
+            with CompileCounter() as cc, self._ambient_mesh():
                 out = self._step_jit(state, batch)
             self.compile_counts[bucket] = cc.count
         else:
-            out = self._step_jit(state, batch)
-        if bucket is not None:
-            self.bucket_steps[bucket] = self.bucket_steps.get(bucket, 0) + 1
+            # a warm bucket must never compile again; counted by the jit's
+            # executables, not a CompileCounter, which would take the
+            # compile away from a caller's enclosing counter
+            n0 = self.executable_count()
+            with self._ambient_mesh():
+                out = self._step_jit(state, batch)
+            if grew := self.executable_count() - n0:
+                self.warm_compiles[bucket] = (self.warm_compiles.get(bucket, 0)
+                                              + grew)
+        self.bucket_steps[bucket] = self.bucket_steps.get(bucket, 0) + 1
         return out
+
+    def compiled_text(self, state: TrainState, batch) -> str:
+        """Optimized HLO of the step executable for these shapes — where a
+        caller checks which kernels (``tpu_custom_call``) the step runs."""
+        with self._ambient_mesh():
+            return self._step_jit.lower(state, batch).compile().as_text()
+
+    def _ambient_mesh(self):
+        """The mesh as the ambient mesh while the step traces, so kernels
+        XLA cannot partition run per device (kernels.ops.bus_attention)."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
 
     def executable_count(self) -> int:
         """Number of distinct compiled executables behind the step jit."""
@@ -390,6 +417,7 @@ class Trainer:
         """
         t0 = time.time()
         cc0, bs0 = dict(self.compile_counts), dict(self.bucket_steps)
+        wc0 = dict(self.warm_compiles)
         state = state if state is not None else self.init_state(seed)
         resumed = None
         if ckpt_dir and ckpt.latest_step(ckpt_dir) is not None:
@@ -519,6 +547,8 @@ class Trainer:
                     .items() if v - cc0.get(k, 0) > 0}
         bsteps = {k: v - bs0.get(k, 0) for k, v in self.bucket_steps.items()
                   if v - bs0.get(k, 0) > 0}
+        warm = {k: v - wc0.get(k, 0) for k, v in self.warm_compiles.items()
+                if v - wc0.get(k, 0) > 0}
         return TrainResult(step, buf.losses, resumed, wall, final,
-                           compiles, bsteps, stall / max(wall, 1e-9),
+                           compiles, warm, bsteps, stall / max(wall, 1e-9),
                            state=state)

@@ -77,6 +77,22 @@ def test_sharded_step_matches_single_device(mesh, cfg):
         assert emb.sharding.spec[0] is not None
 
 
+def test_pallas_bus_kernel_runs_per_shard_under_mesh(mesh):
+    """XLA cannot partition a Mosaic call, so under the Trainer's mesh the
+    bus kernel runs inside a shard_map over the news axis: the sharded
+    step still computes the single-device step."""
+    cfg = small_speedyfeed_config(attn_impl="pallas")
+    tr1 = training.get_trainer("speedyfeed", cfg=cfg)
+    trm = training.get_trainer("speedyfeed", cfg=cfg, mesh=mesh)
+    s1, sm = tr1.init_state(0), trm.init_state(0)
+    for i in range(2):
+        b = _synth(cfg, i)
+        s1, m1 = tr1.step(s1, jax.device_put(b))
+        sm, mm = trm.step(sm, b)
+        np.testing.assert_allclose(float(mm["loss"]), float(m1["loss"]),
+                                   rtol=0, atol=1e-5)
+
+
 def test_sharded_step_donates_state(mesh, cfg):
     trm = training.get_trainer("speedyfeed", cfg=cfg, mesh=mesh)
     s0, _ = trm.step(trm.init_state(0), _synth(cfg, 0))   # committed state

@@ -82,8 +82,7 @@ def test_dryrun_machinery_tiny_mesh():
     mesh = make_mesh_for(1, model=1)
     fn = cell.make_fn(mesh)
     args = cell.abstract_args(mesh)
-    from repro.launch.mesh import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn).lower(*args).compile()
     r = rl.from_compiled(cell, compiled, "1x1", 1)
     assert r.flops_per_chip > 0

@@ -150,7 +150,26 @@ def test_k_buckets_compile_exactly_k_executables():
             state, metrics = trainer.step(state, batch, bucket=b)
     assert cc.count == 0
     assert trainer.executable_count() == len(buckets)
+    assert trainer.warm_compiles == {}
     assert np.isfinite(float(jax.device_get(metrics["loss"])))
+
+
+def test_warm_bucket_recompile_is_recorded():
+    """A second shape under a bucket label that already compiled is a
+    warm recompile: the Trainer records it per bucket, and an enclosing
+    CompileCounter still sees it."""
+    cfg = tiny_cfg()
+    trainer = training.get_trainer("speedyfeed", cfg=cfg)
+    state = trainer.init_state(seed=0)
+    state, _ = trainer.step(state, jax.device_put(synth_batch(cfg, 8)),
+                            bucket=8)
+    assert trainer.warm_compiles == {}
+    with training.CompileCounter() as cc:
+        state, _ = trainer.step(
+            state, jax.device_put(synth_batch(cfg, 16, seed=1)), bucket=8)
+    assert trainer.warm_compiles == {8: 1}
+    assert cc.count >= 1
+    assert trainer.bucket_steps == {8: 2}
 
 
 def test_fit_with_pallas_attention_under_remat():
