@@ -1,0 +1,11 @@
+"""The benchmark's own tests (not the repository's tier-1 suite):
+
+    PYTHONPATH=src:. JAX_PLATFORMS=cpu python -m pytest -q bench/tests
+"""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
