@@ -70,7 +70,9 @@ def make_sf_train_step(cfg: core.SpeedyFeedConfig):
     def step_fn(params, opt_state, cache, step, rng, batch):
         (loss, (new_cache, metrics)), grads = gfn(params, batch, cache,
                                                   step, rng)
-        params, opt_state, om = adam_update(params, grads, opt_state, SF_OPT)
+        with jax.named_scope("update"):     # clipping + Adam
+            params, opt_state, om = adam_update(params, grads, opt_state,
+                                                SF_OPT)
         metrics = dict(metrics)
         metrics.update(om)
         metrics["loss"] = loss

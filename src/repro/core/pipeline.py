@@ -89,49 +89,69 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
     rng_cache, rng_neg = jax.random.split(rng)
     news_ids = batch["news_ids"]
 
+    # Each stage runs under a named scope (plm_encode, cache, user_model,
+    # loss; the optimizer adds update), so every device op of the step
+    # carries its stage in its name stack -- through autodiff
+    # (``transpose(jvp(plm_encode))``) and remat (``checkpoint``) too.
+
     # (2) cache plan + (3) encode the budget set
     # The merged set is replicated (global dedup/argsort); the ENCODE set is
     # explicitly data-sharded so the PLM runs data-parallel — without this
     # constraint XLA keeps the whole encoder replicated (16x the FLOPs/chip;
     # see EXPERIMENTS.md §Perf/H1).
     from repro.distributed import sharding as shx
-    plan = cache_plan(cache, news_ids, step, rng_cache, cfg.cache)
-    enc_tokens = shx.constrain(
-        jnp.take(batch["news_tokens"], plan.enc_pos, axis=0), "encode_batch")
-    enc_freq = shx.constrain(
-        jnp.take(batch["news_freq"], plan.enc_pos, axis=0), "encode_batch")
-    new_emb = buslm_encode(params["plm"], cfg.plm, enc_tokens, enc_freq,
-                           impl=cfg.attn_impl)
+    with jax.named_scope("cache"):
+        plan = cache_plan(cache, news_ids, step, rng_cache, cfg.cache)
+        enc_tokens = shx.constrain(
+            jnp.take(batch["news_tokens"], plan.enc_pos, axis=0),
+            "encode_batch")
+        enc_freq = shx.constrain(
+            jnp.take(batch["news_freq"], plan.enc_pos, axis=0),
+            "encode_batch")
+    with jax.named_scope("plm_encode"):
+        new_emb = buslm_encode(params["plm"], cfg.plm, enc_tokens, enc_freq,
+                               impl=cfg.attn_impl)
 
     # (4) assemble merged-set embeddings and dispatch
-    emb_m = assemble_embeddings(cache, plan, news_ids, new_emb)
-    theta = dispatch(emb_m, batch["hist_inv"])           # [B, L, d]
+    with jax.named_scope("cache"):
+        emb_m = assemble_embeddings(cache, plan, news_ids, new_emb)
+        theta = dispatch(emb_m, batch["hist_inv"])           # [B, L, d]
     mask = batch["hist_mask"]
 
     # (5) autoregressive user modeling + Eq. 5
-    mu = user_embeddings(params["user"], cfg.user, theta, mask)
-    neg_idx = sample_negatives(rng_neg, cfg.merged_cap,
-                               mask[:, 1:].shape, cfg.n_neg)
-    loss, m = ar_loss(mu, theta, mask, emb_m, news_ids, neg_idx,
-                      hist_inv=batch["hist_inv"])
+    with jax.named_scope("user_model"):
+        mu = user_embeddings(params["user"], cfg.user, theta, mask)
+    with jax.named_scope("loss"):
+        neg_idx = sample_negatives(rng_neg, cfg.merged_cap,
+                                   mask[:, 1:].shape, cfg.n_neg)
+        loss, m = ar_loss(mu, theta, mask, emb_m, news_ids, neg_idx,
+                          hist_inv=batch["hist_inv"])
 
-    # (6) refresh
-    new_cache = cache_refresh(cache, plan, news_ids, new_emb, step)
-
-    tok_valid = (enc_tokens != 0).sum()
-    m.update({
-        "p_t": plan.p_t,
-        "encoded": plan.enc_valid.sum(),
-        "reused": plan.reuse.sum(),
-        "cache_overflow": plan.overflow,
-        # cache hit/miss/expired device scalars (cache.py age math); the
-        # Trainer's MetricsBuffer drain folds them into obs counters —
-        # the paper's headline cache-reuse signal, no extra syncs
-        "cache_hits": plan.reuse.sum(),
-        "cache_misses": plan.missing.sum(),
-        "cache_expired": plan.expired.sum(),
-        "data_efficiency": tok_valid / jnp.maximum(enc_tokens.size, 1),
-    })
+    # (6) refresh, and the step's counts of the cache and the encode set
+    with jax.named_scope("cache"):
+        new_cache = cache_refresh(cache, plan, news_ids, new_emb, step)
+        encoded = plan.enc_valid.sum()
+        _, K, S = enc_tokens.shape
+        m.update({
+            "p_t": plan.p_t,
+            "encoded": encoded,
+            "reused": plan.reuse.sum(),
+            "cache_overflow": plan.overflow,
+            # cache hit/miss/expired device scalars (cache.py age math); the
+            # Trainer's MetricsBuffer drain folds them into obs counters —
+            # the paper's headline cache-reuse signal, no extra syncs
+            "cache_hits": plan.reuse.sum(),
+            "cache_misses": plan.missing.sum(),
+            "cache_expired": plan.expired.sum(),
+            # the encoder's padding: rows of the E-row encode set that
+            # needed encoding, and their real tokens of the K x S slots
+            # each such row runs (the Trainer sums them per drain)
+            "encode_rows": jnp.int32(plan.enc_pos.shape[0]),
+            "enc_tokens": ((enc_tokens != 0)
+                           & plan.enc_valid[:, None, None]).sum(),
+            "enc_token_slots": encoded * (K * S),
+            "merged_news": (news_ids != 0).sum(),
+        })
     return StepOut(loss, new_cache, m)
 
 

@@ -151,6 +151,8 @@ def build_centralized_batch(instances, store: NewsStore, cfg: LoaderConfig,
         "_bucket": seg_len,
         "_stats": {
             "seg_len": seg_len,
+            "users": int(mask.any(1).sum()),      # real users of the B slots
+            "user_slots": B,
             "n_unique": int(len(uniq)),
             "n_news_slots": int(mask.sum()),
             "data_efficiency": valid / max(used.size, 1),

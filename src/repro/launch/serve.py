@@ -130,8 +130,11 @@ class Recommender:
         # params are arguments, not closed-over constants: a closure bakes
         # them into the executable (858 MB at PROD width, past the
         # persistent compilation cache's entry limit)
-        self._encode = jax.jit(
-            lambda p, t, f: core.buslm_encode(p, cfg.plm, t, f))
+        def encode(p, t, f):
+            with jax.named_scope("plm_encode"):
+                return core.buslm_encode(p, cfg.plm, t, f)
+
+        self._encode = jax.jit(encode)
 
         def user_encode(p, emb, hist, hist_mask):
             return core.attentive_user(p, emb[hist], hist_mask)
@@ -139,22 +142,36 @@ class Recommender:
         self._user = jax.jit(user_encode)
 
     def _encode_corpus(self, *, chunk: int = 256):
-        """Offline bulk encode of the whole corpus (cells: encode_bulk)."""
+        """Offline bulk encode of the whole corpus (cells: encode_bulk).
+
+        Spans ``encode_corpus`` (the call), ``encode_chunk`` (each chunk)
+        and ``encode_fetch`` (the wait for a chunk's embeddings and their
+        copy to the host); per chunk, ``encode_window`` counts its corpus
+        rows, their real tokens and the token slots the encoder runs (the
+        padded tail's included)."""
         toks = self.store.tokens
         n = toks.shape[0]
+        slots = chunk * int(np.prod(toks.shape[1:]))
         plm = self.params["plm"]
         outs = []
-        for i in range(0, n, chunk):
-            t = jnp.asarray(toks[i:i + chunk])
-            f = jnp.asarray(self.store.freq[i:i + chunk])
-            if t.shape[0] < chunk:   # pad the tail to the warm shape
-                pad = chunk - t.shape[0]
-                t = jnp.pad(t, ((0, pad), (0, 0), (0, 0)))
-                f = jnp.pad(f, ((0, pad), (0, 0), (0, 0)))
-                outs.append(np.asarray(self._encode(plm, t, f))[:-pad])
-            else:
-                outs.append(np.asarray(self._encode(plm, t, f)))
-        emb = np.concatenate(outs)
+        with obs.span("encode_corpus"):
+            for i in range(0, n, chunk):
+                with obs.span("encode_chunk"):
+                    tc = toks[i:i + chunk]
+                    obs.counts("encode_window", rows=tc.shape[0],
+                               tokens=int(np.count_nonzero(tc)),
+                               token_slots=slots)
+                    t = jnp.asarray(tc)
+                    f = jnp.asarray(self.store.freq[i:i + chunk])
+                    pad = chunk - t.shape[0]
+                    if pad:             # pad the tail to the warm shape
+                        t = jnp.pad(t, ((0, pad), (0, 0), (0, 0)))
+                        f = jnp.pad(f, ((0, pad), (0, 0), (0, 0)))
+                    out = self._encode(plm, t, f)
+                    with obs.span("encode_fetch"):
+                        out = np.asarray(out)
+                    outs.append(out[:-pad] if pad else out)
+            emb = np.concatenate(outs)
         emb[0] = 0.0              # pad news scores nothing
         return emb
 

@@ -5,8 +5,9 @@ without measurement: embedding-cache reuse (§4.1.2), eliminated
 non-informative encoding, pipeline overlap.  This package is the one
 place they all report to — a process-wide ``MetricsRegistry`` of
 counters / gauges / log2 latency histograms, a ``span`` context manager
-for wall-time sections (forwarding to ``jax.profiler.TraceAnnotation``
-inside a profiler trace), and exporters (JSONL snapshots, Prometheus
+for wall-time sections and a ``counts`` helper for counts of a stretch
+of work (both forwarding to ``jax.profiler.TraceAnnotation`` inside a
+profiler trace, labels and values as the event's stats), and exporters (JSONL snapshots, Prometheus
 text, periodic in-loop Reporter).
 
 Everything instrumented writes to the module-default registry via the
@@ -16,6 +17,7 @@ helpers below:
     obs.gauge("prefetch_queue_depth").set(q.qsize())
     obs.histogram("query_latency_ms", phase="e2e").observe(ms)
     with obs.span("index_rebuild", mode="full"): ...
+    obs.counts("encode_window", rows=256, tokens=n)   # counters + trace
     obs.write_jsonl("metrics.jsonl")
 
 Launcher entry points call ``obs.reset()`` on startup so one run's
@@ -32,7 +34,7 @@ from ._default import registry as default_registry
 from .export import Reporter, prometheus_text, write_jsonl
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        bucket_le, series_key)
-from .span import set_trace_annotations, span
+from .span import counts, set_trace_annotations, span
 
 _reporter: Reporter | None = None
 

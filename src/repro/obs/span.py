@@ -9,9 +9,17 @@ into their own series without interference (per-series locks).
 
 When a JAX profiler trace is being captured, spans additionally forward
 to ``jax.profiler.TraceAnnotation`` so the same names show up on the
-host timeline of the trace viewer next to the XLA device lanes.  The
-forwarding is auto-detected per span entry (cheap: one attribute read)
-and can be forced on/off with ``set_trace_annotations``.
+host timeline of the trace viewer next to the XLA device lanes, with the
+labels (and any ``trace_args``, which stay out of the registry) as the
+event's stats.  The forwarding is auto-detected per span entry (cheap:
+one attribute read) and can be forced on/off with
+``set_trace_annotations``.
+
+``counts("train_window", steps=20, encoded=5120)`` adds each value to the
+counter ``<name>_<key>_total`` and, while a trace is being captured,
+writes one zero-work ``TraceAnnotation`` carrying the values, so that
+counts made for a stretch of work sit at that moment on the trace's clock
+(a reader sums the ones inside its window).
 """
 from __future__ import annotations
 
@@ -55,13 +63,18 @@ class span:
     itself — create per use for concurrent timing.
     """
 
-    __slots__ = ("_hist", "_name", "_t0", "_ta")
+    __slots__ = ("_hist", "_name", "_args", "_t0", "_ta")
 
-    def __init__(self, name: str, *, registry=None, **labels):
+    def __init__(self, name: str, *, registry=None, trace_args=None,
+                 **labels):
+        """``labels`` key the ``span_ms`` series and go to the trace;
+        ``trace_args`` (per-call values such as a batch's user count)
+        go to the trace only, so they add no series."""
         reg = registry if registry is not None else _default.registry()
         self._name = name
         self._hist = reg.histogram("span_ms", name=name, **labels) \
             if reg.enabled else None
+        self._args = {**labels, **trace_args} if trace_args else labels
         self._ta = None
 
     def __enter__(self):
@@ -70,7 +83,7 @@ class span:
         if _profiling_active():
             try:
                 from jax.profiler import TraceAnnotation
-                self._ta = TraceAnnotation(self._name)
+                self._ta = TraceAnnotation(self._name, **self._args)
                 self._ta.__enter__()
             except Exception:
                 self._ta = None
@@ -84,3 +97,18 @@ class span:
                 self._ta.__exit__(*exc)
                 self._ta = None
         return False
+
+
+def counts(name: str, /, *, registry=None, **values):
+    """Add each value to the counter ``<name>_<key>_total``; while a
+    profiler trace is being captured, also write one ``TraceAnnotation``
+    named ``name`` whose stats are the values."""
+    reg = registry if registry is not None else _default.registry()
+    if not reg.enabled:
+        return
+    for key, v in values.items():
+        reg.counter(f"{name}_{key}_total").inc(v)
+    if _profiling_active():
+        from jax.profiler import TraceAnnotation
+        with TraceAnnotation(name, **values):
+            pass

@@ -166,10 +166,22 @@ _CACHE_COUNTER_KEYS = (
 )
 
 
-def _feed_cache_obs(host_metrics: list):
+# per-step device counts of the encode set and the cache, summed over each
+# drain into one ``train_window`` count (obs.counts): counters, and, while
+# a profiler trace is being captured, an event on the trace's clock whose
+# stats a benchmark window sums
+_WINDOW_KEYS = ("encoded", "encode_rows", "enc_tokens", "enc_token_slots",
+                "cache_hits", "merged_news", "cache_overflow")
+
+
+def _feed_drain_obs(host_metrics: list):
     """MetricsBuffer drain hook: fold the drained per-step cache scalars
     into obs counters and refresh the derived hit-rate gauge (plus the
-    non-finite-guard skip counter, which drains on the same cadence)."""
+    non-finite-guard skip counter, which drains on the same cadence), and
+    write the drained steps' sums as one ``train_window`` count."""
+    obs.counts("train_window", steps=len(host_metrics),
+               **{k: int(sum(int(m[k]) for m in host_metrics))
+                  for k in _WINDOW_KEYS if k in host_metrics[0]})
     skipped = sum(float(m.get("nonfinite_step", 0.0)) for m in host_metrics)
     if skipped:
         obs.counter("train_nonfinite_steps_total").inc(skipped)
@@ -261,6 +273,7 @@ class Trainer:
         self.bucket_steps: dict = {}      # bucket -> steps run
         self.monitor: StepTimeMonitor | None = None   # set by fit()
         self.last_state: TrainState | None = None     # final state of fit()
+        self.metrics_buffer: MetricsBuffer | None = None   # of the last fit
         # compile events flow into the obs registry for every fit, not
         # only while a CompileCounter is explicitly active
         ensure_compile_listener()
@@ -273,14 +286,15 @@ class Trainer:
             state.params, state.opt, state.cache, state.step, rng, batch)
         if self._nonfinite_guard and isinstance(metrics, dict) \
                 and "loss" in metrics:
-            ok = jnp.isfinite(metrics["loss"])
+            with jax.named_scope("update"):
+                ok = jnp.isfinite(metrics["loss"])
 
-            def keep(new, old):
-                return jnp.where(ok, new, old)
+                def keep(new, old):
+                    return jnp.where(ok, new, old)
 
-            params = jax.tree.map(keep, params, state.params)
-            opt = jax.tree.map(keep, opt, state.opt)
-            cache = jax.tree.map(keep, cache, state.cache)
+                params = jax.tree.map(keep, params, state.params)
+                opt = jax.tree.map(keep, opt, state.opt)
+                cache = jax.tree.map(keep, cache, state.cache)
             metrics = dict(metrics)
             metrics["nonfinite_step"] = 1.0 - ok.astype(jnp.float32)
         new = TrainState(params, opt, cache, state.step + 1, state.rng)
@@ -451,10 +465,9 @@ class Trainer:
             else None).start()
         n_hosts = hosts if hosts is not None else jax.process_count()
         monitor = StepTimeMonitor(n_hosts=max(n_hosts, 1))
-        buf = MetricsBuffer(on_drain=_feed_cache_obs)
+        buf = MetricsBuffer(on_drain=_feed_drain_obs)
         stall, de_sum, de_n = 0.0, 0.0, 0
         drain_mark, drain_step = time.perf_counter(), step
-        step_hists: dict = {}     # bucket -> train_step_ms histogram
         step_ctrs: dict = {}      # bucket -> train_steps_total counter
         try:
             while step < steps:
@@ -467,23 +480,23 @@ class Trainer:
                 if pb is None:
                     raise RuntimeError(
                         f"no batch within {batch_timeout}s at step {step}")
-                state, metrics = self.step(state, pb.arrays, pb.bucket)
+                # the dispatch, named on the trace with the batch's fill
+                st = pb.stats or {}
+                with obs.span("train_step", bucket=str(pb.bucket),
+                              trace_args={k: st[k] for k in
+                                          ("users", "user_slots")
+                                          if k in st}):
+                    state, metrics = self.step(state, pb.arrays, pb.bucket)
                 buf.append(metrics)
                 if pb.stats and "data_efficiency" in pb.stats:
                     de_sum += float(pb.stats["data_efficiency"])
                     de_n += 1
                 step += 1
-                # per-step wall at the loop (dispatch + stall; converges to
-                # true step time once the async queue backpressures)
-                hist = step_hists.get(pb.bucket)
-                if hist is None:
-                    b = str(pb.bucket)
-                    hist = step_hists[pb.bucket] = obs.histogram(
-                        "train_step_ms", bucket=b)
-                    step_ctrs[pb.bucket] = obs.counter(
-                        "train_steps_total", bucket=b)
-                hist.observe((time.perf_counter() - t_iter) * 1e3)
-                step_ctrs[pb.bucket].inc()
+                ctr = step_ctrs.get(pb.bucket)
+                if ctr is None:
+                    ctr = step_ctrs[pb.bucket] = obs.counter(
+                        "train_steps_total", bucket=str(pb.bucket))
+                ctr.inc()
                 if monitor.n_hosts > 1:
                     # simulated multi-host: attribute per-step loop wall
                     # round-robin (real multi-process runs would record
@@ -536,11 +549,11 @@ class Trainer:
                 writer.wait()
         self.monitor = monitor
         self.last_state = state
+        self.metrics_buffer = buf
         final = buf.drain()
         if de_n:      # loader-side Eq. 1 data efficiency (paper Figure 8)
             final["loader_data_efficiency"] = de_sum / de_n
         wall = time.time() - t0
-        obs.gauge("train_host_stall_fraction").set(stall / max(wall, 1e-9))
         # report THIS run's deltas (the Trainer's own counters are
         # cumulative across its lifetime, e.g. warm-up + repeated fits)
         compiles = {k: v - cc0.get(k, 0) for k, v in self.compile_counts

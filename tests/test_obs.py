@@ -378,3 +378,53 @@ def test_module_helpers_and_reset():
     obs.counter("a").inc()
     assert obs.counter("a").value == 0 and not obs.enabled()
     obs.set_enabled(True)
+
+
+# ---------------------------------------------------------------------------
+# spans and counts on the profiler's clock: labels, trace-only args and
+# count values become stats of host events in the trace
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """[(name, stats dict)] of every host event of the one trace under
+    ``trace_dir``, read back as the benchmark reads it."""
+    from jax.profiler import ProfileData
+    (path,) = list(trace_dir.glob("**/*.xplane.pb"))
+    p = ProfileData.from_file(str(path))
+    return [(e.name, dict(e.stats)) for plane in p.planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events]
+
+
+def test_span_and_counts_land_as_trace_stats(tmp_path):
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("encode_chunk", stage="fwd",
+                      trace_args={"users": 17}):
+            pass
+        obs.counts("encode_window", rows=256, tokens=4321)
+        obs.counts("encode_window", rows=1, tokens=7)
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(tmp_path)
+    spans = [st for n, st in ev if n == "encode_chunk"]
+    assert spans == [{"stage": "fwd", "users": 17}]
+    assert [st for n, st in ev if n == "encode_window"] == [
+        {"rows": 256, "tokens": 4321}, {"rows": 1, "tokens": 7}]
+    # the registry counts the same values; trace-only args add no series
+    assert obs.counter("encode_window_rows_total").value == 257
+    assert obs.counter("encode_window_tokens_total").value == 4328
+    assert 'span_ms{name="encode_chunk",stage="fwd"}' in obs.collect()
+    assert not any("users" in k for k in obs.collect())
+
+
+def test_counts_without_a_trace_and_disabled():
+    reg = MetricsRegistry()
+    obs.counts("w", registry=reg, a=2, b=3)
+    obs.counts("w", registry=reg, a=1, b=0)
+    assert reg.counter("w_a_total").value == 3
+    assert reg.counter("w_b_total").value == 3
+    off = MetricsRegistry(enabled=False)
+    obs.counts("w", registry=off, a=2)
+    assert off.collect() == {}

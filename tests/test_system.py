@@ -108,3 +108,40 @@ def test_news_baselines_train_step():
             optim.AdamConfig(lr=1e-3))
         params, _, m = jax.jit(step)(params, optim.adam_init(params), batch)
         assert np.isfinite(float(m["loss"]))
+
+
+def test_bulk_encode_spans_and_counts():
+    """The corpus encode names its parts (``encode_corpus`` per call,
+    ``encode_chunk`` and ``encode_fetch`` per chunk) and counts each
+    chunk's rows, real tokens and encoder token slots (padded tail
+    included) as ``encode_window``; the chunking changes no embedding."""
+    import types
+    from repro import core, obs
+    from repro.launch.serve import Recommender
+    obs.reset()
+    cfg = core.make_config(vocab=200, n_layers=1, d_model=32, n_heads=2,
+                           d_ff=64, n_segments=3, seg_len=8, news_dim=16,
+                           n_news=21, encode_budget=8, batch_users=4,
+                           hist_len=6, merged_cap=16, n_neg=2)
+    params, _ = core.speedyfeed_state(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(1, 200, (21, 3, 8)).astype(np.int32)
+    tokens *= rng.random((21, 3, 8)) < 0.6            # ragged news
+    tokens[0] = 0                                     # the pad news
+    store = types.SimpleNamespace(tokens=tokens, freq=(tokens > 0)
+                                  .astype(np.int32))
+    rec = Recommender(cfg, params, store)
+    emb = rec._encode_corpus(chunk=8)
+    whole = np.asarray(rec._encode(params["plm"], jnp.asarray(tokens),
+                                   jnp.asarray(store.freq)))
+    np.testing.assert_allclose(emb[1:], whole[1:], rtol=1e-5, atol=1e-5)
+    assert obs.counter("encode_window_rows_total").value == 21
+    assert obs.counter("encode_window_tokens_total").value \
+        == np.count_nonzero(tokens)
+    assert obs.counter("encode_window_token_slots_total").value \
+        == 3 * 8 * 3 * 8
+    spans = {n: obs.histogram("span_ms", name=n).count
+             for n in ("encode_corpus", "encode_chunk", "encode_fetch")}
+    assert spans == {"encode_corpus": 1, "encode_chunk": 3,
+                     "encode_fetch": 3}
+    obs.reset()

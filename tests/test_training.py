@@ -345,3 +345,101 @@ def test_registry_exposes_trainers():
     assert "speedyfeed_conventional" in names
     with pytest.raises(KeyError):
         training.get_trainer("no-such-arch")
+
+
+# ---------------------------------------------------------------------------
+# the step on the profiler's clock: one train_step span per dispatch, the
+# drained steps' counts as train_window events, and a program scope on
+# every op of the step
+# ---------------------------------------------------------------------------
+
+SCOPES = ("plm_encode", "cache", "user_model", "loss", "update")
+
+
+def test_traced_fit_writes_step_spans_and_window_counts(tmp_path):
+    from jax.profiler import ProfileData
+    from repro import obs
+    obs.reset()
+    cfg = tiny_cfg(beta=5.0)          # lookups from the first steps on
+    corpus, log, store, lcfg = make_loader(cfg, n_news=60, n_users=30,
+                                           seed=2)
+    trainer = training.get_trainer("speedyfeed", cfg=cfg)
+
+    def make_batcher(epoch):
+        return data.DynamicBatcher(log, store, lcfg, n_threads=2,
+                                   seed=epoch).start()
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0          # as the benchmark traces
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        res = trainer.fit(make_batcher, steps=7, log_every=3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = list(tmp_path.glob("**/*.xplane.pb"))
+    events = [(e.name, dict(e.stats))
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    steps = [st for n, st in events if n == "train_step"]
+    assert len(steps) == res.steps_done == 7
+    for st in steps:
+        assert int(st["bucket"]) in lcfg.buckets
+        assert 1 <= st["users"] <= st["user_slots"] == cfg.batch_users
+    # drains at steps 3 and 6 and the final one inside fit
+    window = [st for n, st in events if n == "train_window"]
+    assert [st["steps"] for st in window] == [3, 3, 1]
+    hist = trainer.metrics_buffer.history
+    for key in ("encoded", "cache_hits", "enc_tokens", "merged_news"):
+        assert sum(st[key] for st in window) == sum(hist[key])
+    assert sum(hist["cache_hits"]) > 0
+    E, K = cfg.cache.encode_budget, cfg.plm.n_segments
+    assert sum(st["encode_rows"] for st in window) == 7 * E
+    assert 0 < sum(st["enc_tokens"] for st in window) \
+        <= sum(st["enc_token_slots"] for st in window)
+    slots = sum(e * K * b for e, b in zip(
+        hist["encoded"], [int(st["bucket"]) for st in steps]))
+    assert sum(st["enc_token_slots"] for st in window) == slots
+    assert obs.counter("train_window_steps_total").value == 7
+    assert obs.histogram("span_ms", name="train_step",
+                         bucket=str(steps[0]["bucket"])).count >= 1
+    obs.reset()
+
+
+def _scope_names(op_name: str) -> set:
+    """Every name in a name stack, wrappers such as
+    ``transpose(jvp(plm_encode))`` unwrapped."""
+    import re
+    names = set()
+    for comp in op_name.split("/"):
+        while (m := re.fullmatch(r"([^()]*)\((.*)\)", comp)):
+            names.add(m.group(1))
+            comp = m.group(2)
+        names.add(comp)
+    return names
+
+
+@pytest.mark.parametrize("remat,attn_impl", [(False, "xla"), (True, "xla"),
+                                             (True, "pallas")])
+def test_step_ops_carry_program_scopes(remat, attn_impl):
+    """Forward, remat recompute and backward -- the bus kernel's custom
+    VJP included -- keep the stage's scope in every op's name stack."""
+    import re
+    cfg = tiny_cfg(remat=remat, attn_impl=attn_impl)
+    trainer = training.get_trainer("speedyfeed", cfg=cfg)
+    state = trainer.init_state(seed=0)
+    batch = jax.device_put(synth_batch(cfg, 16))
+    text = trainer.compiled_text(state, batch)
+    ops = [ln for ln in text.splitlines()
+           if re.search(r" (dot|convolution|custom-call)\(", ln)]
+    assert ops
+    seen = set()
+    for ln in ops:
+        m = re.search(r'op_name="([^"]*)"', ln)
+        assert m, ln
+        names = _scope_names(m.group(1)) & set(SCOPES)
+        assert len(names) == 1, m.group(1)
+        seen |= names
+    assert {"plm_encode", "user_model", "loss"} <= seen
+    if remat:
+        assert "checkpoint" in text
