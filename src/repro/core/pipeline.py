@@ -4,7 +4,8 @@ One training step over a centralized batch:
   1. merged news set M (deduplicated by the loader or by gather_dedup)
   2. cache plan: which news reuse cached embeddings, which get encoded
      (fixed budget E; p_t scheduler; gamma expiry)                  §4.1.2
-  3. BusLM-encode the encode set                                    §4.1.3
+  3. BusLM-encode the encode set, chunk by chunk, skipping the
+     chunks that hold no news to encode                             §4.1.3
   4. assemble + dispatch embeddings to history positions            §4.1.1
   5. autoregressive user modeling + Eq.5 loss over all L positions  §4.1.4
   6. refresh cache
@@ -74,6 +75,57 @@ def init_speedyfeed(key, cfg: SpeedyFeedConfig, param_dtype=jnp.float32):
             "user": init_user_model(k2, cfg.user, param_dtype)}
 
 
+def encode_chunk_rows(n_rows: int) -> int:
+    """Rows per chunk of an ``n_rows``-row encode set: a sixteenth of it
+    when ``n_rows`` is a multiple of 128 (so a chunk is a whole number of
+    the bus kernel's 8-row blocks), else the whole set in one chunk."""
+    return n_rows // 16 if n_rows % 128 == 0 else n_rows
+
+
+def encode_set(plm_params, cfg: SpeedyFeedConfig, tokens, freq, n_valid):
+    """BusLM-encode the encode set's ``n_valid`` rows that need encoding.
+
+    ``cache_plan`` orders the must-encode rows first, so they are the first
+    ``n_valid`` of the E rows.  The set runs in chunks of
+    ``encode_chunk_rows(E)`` rows, and a chunk that holds none of them is
+    not run: its rows are zeros, which neither ``assemble_embeddings`` nor
+    ``cache_refresh`` reads (both select by ``enc_valid``).  A scan over
+    the chunks with a cond in its body keeps one copy of the encoder,
+    forward and backward, in the program, and only the taken branch runs.
+    A single chunk always runs.  Returns ([E, news_dim] embeddings, the
+    number of rows the encoder ran).
+    """
+    from repro.distributed import sharding as shx
+
+    def encode(p, t, f):
+        # the merged set is replicated (global dedup/argsort); the encode
+        # set is data-sharded so the PLM runs data-parallel -- without
+        # this constraint XLA keeps the whole encoder replicated
+        return buslm_encode(p, cfg.plm, shx.constrain(t, "encode_batch"),
+                            shx.constrain(f, "encode_batch"),
+                            impl=cfg.attn_impl)
+
+    E = tokens.shape[0]
+    G = encode_chunk_rows(E)
+    if G == E:
+        return encode(plm_params, tokens, freq), jnp.int32(E)
+    C = E // G
+    chunks = (jnp.arange(C), tokens.reshape(C, G, *tokens.shape[1:]),
+              freq.reshape(C, G, *freq.shape[1:]))
+    out = jax.eval_shape(encode, plm_params, chunks[1][0], chunks[2][0])
+
+    def skip(p, t, f):
+        return jnp.zeros(out.shape, out.dtype)
+
+    def body(carry, xs):
+        c, t, f = xs
+        return carry, jax.lax.cond(c * G < n_valid, encode, skip,
+                                   plm_params, t, f)
+
+    _, emb = jax.lax.scan(body, None, chunks)
+    return emb.reshape(E, -1), jnp.minimum(-(-n_valid // G), C) * G
+
+
 class StepOut(NamedTuple):
     loss: jax.Array
     cache: CacheState
@@ -95,22 +147,14 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
     # (``transpose(jvp(plm_encode))``) and remat (``checkpoint``) too.
 
     # (2) cache plan + (3) encode the budget set
-    # The merged set is replicated (global dedup/argsort); the ENCODE set is
-    # explicitly data-sharded so the PLM runs data-parallel — without this
-    # constraint XLA keeps the whole encoder replicated (16x the FLOPs/chip;
-    # see EXPERIMENTS.md §Perf/H1).
-    from repro.distributed import sharding as shx
     with jax.named_scope("cache"):
         plan = cache_plan(cache, news_ids, step, rng_cache, cfg.cache)
-        enc_tokens = shx.constrain(
-            jnp.take(batch["news_tokens"], plan.enc_pos, axis=0),
-            "encode_batch")
-        enc_freq = shx.constrain(
-            jnp.take(batch["news_freq"], plan.enc_pos, axis=0),
-            "encode_batch")
+        enc_tokens = jnp.take(batch["news_tokens"], plan.enc_pos, axis=0)
+        enc_freq = jnp.take(batch["news_freq"], plan.enc_pos, axis=0)
+        encoded = plan.enc_valid.sum()
     with jax.named_scope("plm_encode"):
-        new_emb = buslm_encode(params["plm"], cfg.plm, enc_tokens, enc_freq,
-                               impl=cfg.attn_impl)
+        new_emb, rows_run = encode_set(params["plm"], cfg, enc_tokens,
+                                       enc_freq, encoded)
 
     # (4) assemble merged-set embeddings and dispatch
     with jax.named_scope("cache"):
@@ -130,7 +174,6 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
     # (6) refresh, and the step's counts of the cache and the encode set
     with jax.named_scope("cache"):
         new_cache = cache_refresh(cache, plan, news_ids, new_emb, step)
-        encoded = plan.enc_valid.sum()
         _, K, S = enc_tokens.shape
         m.update({
             "p_t": plan.p_t,
@@ -147,6 +190,8 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
             # needed encoding, and their real tokens of the K x S slots
             # each such row runs (the Trainer sums them per drain)
             "encode_rows": jnp.int32(plan.enc_pos.shape[0]),
+            # rows of the chunks the encoder ran (encode_set)
+            "encode_rows_run": rows_run,
             "enc_tokens": ((enc_tokens != 0)
                            & plan.enc_valid[:, None, None]).sum(),
             "enc_token_slots": encoded * (K * S),

@@ -170,8 +170,9 @@ _CACHE_COUNTER_KEYS = (
 # drain into one ``train_window`` count (obs.counts): counters, and, while
 # a profiler trace is being captured, an event on the trace's clock whose
 # stats a benchmark window sums
-_WINDOW_KEYS = ("encoded", "encode_rows", "enc_tokens", "enc_token_slots",
-                "cache_hits", "merged_news", "cache_overflow")
+_WINDOW_KEYS = ("encoded", "encode_rows", "encode_rows_run", "enc_tokens",
+                "enc_token_slots", "cache_hits", "merged_news",
+                "cache_overflow")
 
 
 def _feed_drain_obs(host_metrics: list):

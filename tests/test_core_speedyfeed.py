@@ -199,6 +199,57 @@ def test_speedyfeed_step_trains():
     assert losses[-1] < losses[0]    # same batch re-fit: loss must drop
 
 
+@pytest.mark.parametrize("n_real,warm,n_valid", [
+    (5, False, 5),        # fewer must-encode rows than a chunk: one runs
+    (20, False, 20),      # mid-set: the last chunk run is partly valid
+    (200, False, 128),    # overflow: every chunk runs
+    (110, True, 10),      # 100 cache hits: the misses sort to the front
+], ids=["one_chunk", "partial", "overflow", "hits"])
+def test_chunked_encode_matches_all_rows(monkeypatch, n_real, warm, n_valid):
+    """Skipping the chunks past the must-encode prefix gives the loss,
+    gradients and cache of encoding all E rows; only the order of the
+    weight-gradient sums differs."""
+    from repro.core import pipeline
+    cfg = tiny_cfg(n_news=300, encode_budget=128, merged_cap=256, beta=100.0,
+                   remat=True)
+    E = cfg.cache.encode_budget
+    G = pipeline.encode_chunk_rows(E)
+    assert G < E
+    key = jax.random.PRNGKey(0)
+    params, cache = core.speedyfeed_state(cfg, key)
+    step = jnp.int32(0)
+    if warm:             # news 1..100 written at step 0, fresh at step 1
+        cache = core.speedyfeed_forward(params, cfg,
+                                        make_batch(cfg, key, n_real=100),
+                                        cache, step, key).cache
+        step = jnp.int32(1)
+    batch = make_batch(cfg, key, n_real=n_real)
+
+    def run():
+        def loss_fn(p):
+            out = core.speedyfeed_forward(p, cfg, batch, cache, step, key)
+            return out.loss, out
+        return jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(params)
+
+    (loss, out), grads = run()
+    monkeypatch.setattr(pipeline, "encode_chunk_rows", lambda n: n)
+    (loss_all, out_all), grads_all = run()
+
+    assert int(out.metrics["encoded"]) == n_valid
+    assert int(out.metrics["encode_rows_run"]) == -(-n_valid // G) * G
+    assert int(out_all.metrics["encode_rows_run"]) == E
+    np.testing.assert_allclose(float(loss), float(loss_all), rtol=1e-5)
+    top = max(float(jnp.abs(g).max()) for g in jax.tree.leaves(grads_all))
+    for g, g_all in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_all)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(g_all),
+                                   rtol=1e-4, atol=1e-6 * top)
+    np.testing.assert_allclose(np.asarray(out.cache.emb),
+                               np.asarray(out_all.cache.emb),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(out.cache.written_step),
+                                  np.asarray(out_all.cache.written_step))
+
+
 def test_conventional_and_speedy_share_encoder_semantics():
     """Encoding N news via the pipeline's encoder == encoding them via the
     conventional path (the speedup must come from scheduling, not from a

@@ -93,6 +93,28 @@ def test_pallas_bus_kernel_runs_per_shard_under_mesh(mesh):
                                    rtol=0, atol=1e-5)
 
 
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_chunked_encode_under_mesh_matches_single_device(mesh, attn_impl):
+    """A 128-row encode set runs in 8-row chunks, each skipped or run
+    across the mesh: the sharded step still computes the single-device
+    step, and both run the same rows."""
+    cfg = small_speedyfeed_config(encode_budget=128, attn_impl=attn_impl)
+    tr1 = training.get_trainer("speedyfeed", cfg=cfg)
+    trm = training.get_trainer("speedyfeed", cfg=cfg, mesh=mesh)
+    s1, sm = tr1.init_state(0), trm.init_state(0)
+    for i, n_real in enumerate((20, 60)):
+        b = _synth(cfg, i)              # only the first n_real news are real
+        b["news_ids"][n_real + 1:] = 0
+        b["hist_inv"] %= n_real + 1
+        s1, m1 = tr1.step(s1, jax.device_put(b))
+        sm, mm = trm.step(sm, b)
+        np.testing.assert_allclose(float(mm["loss"]), float(m1["loss"]),
+                                   rtol=0, atol=1e-5)
+        assert int(m1["encoded"]) == n_real
+        assert int(mm["encode_rows_run"]) == int(m1["encode_rows_run"]) \
+            == -(-n_real // 8) * 8
+
+
 def test_sharded_step_donates_state(mesh, cfg):
     trm = training.get_trainer("speedyfeed", cfg=cfg, mesh=mesh)
     s0, _ = trm.step(trm.init_state(0), _synth(cfg, 0))   # committed state

@@ -360,7 +360,8 @@ def test_traced_fit_writes_step_spans_and_window_counts(tmp_path):
     from jax.profiler import ProfileData
     from repro import obs
     obs.reset()
-    cfg = tiny_cfg(beta=5.0)          # lookups from the first steps on
+    # lookups from the first steps on; a 128-row encode set runs in chunks
+    cfg = tiny_cfg(beta=5.0, encode_budget=128, merged_cap=256)
     corpus, log, store, lcfg = make_loader(cfg, n_news=60, n_users=30,
                                            seed=2)
     trainer = training.get_trainer("speedyfeed", cfg=cfg)
@@ -390,11 +391,15 @@ def test_traced_fit_writes_step_spans_and_window_counts(tmp_path):
     window = [st for n, st in events if n == "train_window"]
     assert [st["steps"] for st in window] == [3, 3, 1]
     hist = trainer.metrics_buffer.history
-    for key in ("encoded", "cache_hits", "enc_tokens", "merged_news"):
+    for key in ("encoded", "encode_rows_run", "cache_hits", "enc_tokens",
+                "merged_news"):
         assert sum(st[key] for st in window) == sum(hist[key])
     assert sum(hist["cache_hits"]) > 0
     E, K = cfg.cache.encode_budget, cfg.plm.n_segments
     assert sum(st["encode_rows"] for st in window) == 7 * E
+    # at most 48 news a step need encoding: most of the 128 rows never run
+    for st in window:
+        assert st["encoded"] <= st["encode_rows_run"] < st["steps"] * E
     assert 0 < sum(st["enc_tokens"] for st in window) \
         <= sum(st["enc_token_slots"] for st in window)
     slots = sum(e * K * b for e, b in zip(
@@ -419,13 +424,10 @@ def _scope_names(op_name: str) -> set:
     return names
 
 
-@pytest.mark.parametrize("remat,attn_impl", [(False, "xla"), (True, "xla"),
-                                             (True, "pallas")])
-def test_step_ops_carry_program_scopes(remat, attn_impl):
-    """Forward, remat recompute and backward -- the bus kernel's custom
-    VJP included -- keep the stage's scope in every op's name stack."""
+def _step_text_and_scopes(cfg):
+    """The compiled step's text, and the scopes its dot, convolution and
+    custom-call ops carry (each exactly one)."""
     import re
-    cfg = tiny_cfg(remat=remat, attn_impl=attn_impl)
     trainer = training.get_trainer("speedyfeed", cfg=cfg)
     state = trainer.init_state(seed=0)
     batch = jax.device_put(synth_batch(cfg, 16))
@@ -440,6 +442,25 @@ def test_step_ops_carry_program_scopes(remat, attn_impl):
         names = _scope_names(m.group(1)) & set(SCOPES)
         assert len(names) == 1, m.group(1)
         seen |= names
+    return text, seen
+
+
+@pytest.mark.parametrize("remat,attn_impl", [(False, "xla"), (True, "xla"),
+                                             (True, "pallas")])
+def test_step_ops_carry_program_scopes(remat, attn_impl):
+    """Forward, remat recompute and backward -- the bus kernel's custom
+    VJP included -- keep the stage's scope in every op's name stack."""
+    text, seen = _step_text_and_scopes(tiny_cfg(remat=remat,
+                                                 attn_impl=attn_impl))
     assert {"plm_encode", "user_model", "loss"} <= seen
     if remat:
         assert "checkpoint" in text
+
+
+def test_chunked_encode_step_keeps_scopes():
+    """A 128-row encode set runs in chunks, each behind a conditional; the
+    encoder's ops inside them still carry ``plm_encode``."""
+    text, seen = _step_text_and_scopes(tiny_cfg(
+        remat=True, attn_impl="pallas", encode_budget=128, merged_cap=256))
+    assert {"plm_encode", "user_model", "loss"} <= seen
+    assert " conditional(" in text
