@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import core, optim, training
 from repro.distributed import sharding as shx
+from repro.nn.moe import RoutedMoEConfig
 from repro.optim.adam import adam_update
 from .base import (Arch, Cell, F32, I32, abstract_opt, abstract_params,
                    assert_finite, batch_sds, data_axes, opt_spec_tree, sds,
@@ -56,6 +57,31 @@ PROD_1CHIP = dataclasses.replace(
     merged_cap=PROD.merged_cap // PROD_SHARE,
     cache=dataclasses.replace(
         PROD.cache, encode_budget=PROD.cache.encode_budget // PROD_SHARE))
+
+# Moonlight-16B-A3B (huggingface.co/moonshotai/Moonlight-16B-A3B,
+# config.json, model_type deepseek_v3) as the news encoder, trained by the
+# same Trainer, cache and encode set as PROD_1CHIP, as one chip of a
+# deployment in which 8 chips share each layer: this chip holds 8 of the
+# 64 routed experts (the router still scores all 64, top-6) and an eighth
+# of the 163,840-row vocabulary; the attention, router and the 2 shared
+# experts are replicated; the layers left out lie on further pipeline
+# stages.  Every width is published: hidden 2,048, 16 heads, q/k head 192
+# (128 + rope 64), v head 128, latent 512, dense SwiGLU 11,264, experts
+# 1,408.  Cut: 27 layers -> 5 (the leading dense one and 4 expert
+# layers), and PROD_1CHIP's per-chip batch share.  news_dim stays 768, so
+# the cache, user model and loss are PROD_1CHIP's.
+MOONLIGHT_SHARE = 8
+MOONLIGHT_1CHIP = dataclasses.replace(
+    PROD_1CHIP, plm=dataclasses.replace(
+        PROD_1CHIP.plm, vocab=163_840 // MOONLIGHT_SHARE, n_layers=5,
+        d_model=2048, n_heads=16, d_ff=11_264, use_freq_embedding=False,
+        decoder=core.DecoderConfig(
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, rope_theta=50_000.0, rms_eps=1e-5, n_dense=1,
+            moe=RoutedMoEConfig(
+                d_model=2048, d_expert=1408, n_experts=64, top_k=6,
+                n_held=64 // MOONLIGHT_SHARE, n_shared=2,
+                routed_scale=2.446))))
 
 CONV_BATCH = dict(users=512, hist=100, cands=2)  # conventional baseline
 

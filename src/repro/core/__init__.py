@@ -1,6 +1,7 @@
 # SpeedyFeed — the paper's primary contribution, as a composable JAX module.
-from .plm import PLMConfig, additive_attention, init_plm
-from .buslm import buslm_encode, plm_flops
+from .plm import DecoderConfig, PLMConfig, additive_attention, init_plm
+from .buslm import (buslm_encode, decoder_encode, decoder_encode_set,
+                    plm_flops)
 from .cache import (CacheConfig, CachePlan, CacheState, assemble_embeddings,
                     cache_plan, cache_refresh, init_cache)
 from .centralized import MergedSet, dispatch, gather_dedup

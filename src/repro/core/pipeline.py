@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from .plm import PLMConfig, init_plm
-from .buslm import buslm_encode
+from .buslm import buslm_encode, decoder_encode_set
 from .cache import (CacheConfig, CacheState, assemble_embeddings, cache_plan,
                     cache_refresh, init_cache)
 from .centralized import dispatch
@@ -55,12 +55,13 @@ def make_config(*, vocab=30522, n_layers=12, d_model=768, n_heads=12,
                 n_news=1_202_576, gamma=20, beta=2e-3, encode_budget=256,
                 batch_users=32, hist_len=100, merged_cap=512, n_neg=4,
                 user_kind="attentive", use_bus=True, use_freq=True,
-                remat=False, attn_impl="auto") -> SpeedyFeedConfig:
+                remat=False, attn_impl="auto",
+                decoder=None) -> SpeedyFeedConfig:
     plm = PLMConfig(vocab=vocab, n_layers=n_layers, d_model=d_model,
                     n_heads=n_heads, d_ff=d_ff, n_segments=n_segments,
                     seg_len=seg_len, news_dim=news_dim, use_bus=use_bus,
                     use_freq_embedding=use_freq, remat=remat,
-                    attn_impl=attn_impl)
+                    attn_impl=attn_impl, decoder=decoder)
     user = UserModelConfig(news_dim=news_dim, kind=user_kind, causal=True)
     cache = CacheConfig(n_news=n_news, news_dim=news_dim, gamma=gamma,
                         beta=beta, encode_budget=encode_budget)
@@ -92,8 +93,11 @@ def encode_set(plm_params, cfg: SpeedyFeedConfig, tokens, freq, n_valid):
     ``cache_refresh`` reads (both select by ``enc_valid``).  A scan over
     the chunks with a cond in its body keeps one copy of the encoder,
     forward and backward, in the program, and only the taken branch runs.
-    A single chunk always runs.  Returns ([E, news_dim] embeddings, the
-    number of rows the encoder ran).
+    A single chunk always runs.  An expert encoder's set runs in the same
+    chunks with a backward pass of its own
+    (``core.buslm.decoder_encode_set``).  Returns ([E, news_dim]
+    embeddings, the number of rows the encoder ran, and the expert
+    encoder's counts -- none for BusLM).
     """
     from repro.distributed import sharding as shx
 
@@ -107,8 +111,12 @@ def encode_set(plm_params, cfg: SpeedyFeedConfig, tokens, freq, n_valid):
 
     E = tokens.shape[0]
     G = encode_chunk_rows(E)
+    if cfg.plm.decoder is not None:
+        emb, counts = decoder_encode_set(plm_params, cfg.plm, tokens,
+                                         n_valid, G)
+        return emb, jnp.minimum(-(-n_valid // G), E // G) * G, counts
     if G == E:
-        return encode(plm_params, tokens, freq), jnp.int32(E)
+        return encode(plm_params, tokens, freq), jnp.int32(E), {}
     C = E // G
     chunks = (jnp.arange(C), tokens.reshape(C, G, *tokens.shape[1:]),
               freq.reshape(C, G, *freq.shape[1:]))
@@ -123,7 +131,7 @@ def encode_set(plm_params, cfg: SpeedyFeedConfig, tokens, freq, n_valid):
                                    plm_params, t, f)
 
     _, emb = jax.lax.scan(body, None, chunks)
-    return emb.reshape(E, -1), jnp.minimum(-(-n_valid // G), C) * G
+    return emb.reshape(E, -1), jnp.minimum(-(-n_valid // G), C) * G, {}
 
 
 class StepOut(NamedTuple):
@@ -153,8 +161,8 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
         enc_freq = jnp.take(batch["news_freq"], plan.enc_pos, axis=0)
         encoded = plan.enc_valid.sum()
     with jax.named_scope("plm_encode"):
-        new_emb, rows_run = encode_set(params["plm"], cfg, enc_tokens,
-                                       enc_freq, encoded)
+        new_emb, rows_run, enc_counts = encode_set(
+            params["plm"], cfg, enc_tokens, enc_freq, encoded)
 
     # (4) assemble merged-set embeddings and dispatch
     with jax.named_scope("cache"):
@@ -197,6 +205,15 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
             "enc_token_slots": encoded * (K * S),
             "merged_news": (news_ids != 0).sum(),
         })
+        if enc_counts:
+            # an expert encoder's rows routed to the experts held here,
+            # the grouped matmuls' row slots run, and the least and most
+            # rows any held expert took, over the step's layers and chunks
+            rows = enc_counts["expert_rows"]
+            m.update({"moe_routed": enc_counts["routed"],
+                      "moe_slots": enc_counts["slots"],
+                      "moe_rows_least": rows.min(),
+                      "moe_rows_most": rows.max()})
     return StepOut(loss, new_cache, m)
 
 

@@ -30,6 +30,13 @@ the mask travels as int32 [M, K, 1, Sk] (valid 1, masked 0).
 Grid: (M_blocks, K, H); block = one head of one segment for a block of
 news. The ops wrapper pads M up to a block_m multiple (padded rows carry
 an all-False mask and are sliced off) instead of degrading block_m.
+
+Causal encoders (``causal=True``): a query at position i of its segment
+sees the segment's keys 0..i (and the mask's bus columns, which the
+caller sets per segment), so the key mask alone cannot express it; the
+kernel adds the in-segment triangle from iotas, in both passes.  Queries
+and keys may have a head dim D other than the values' Dv (latent
+attention: 192 against 128); the scale is D^-1/2.
 """
 from __future__ import annotations
 
@@ -42,37 +49,51 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
-def _tile_softmax(q, k, mask, scale):
+def _visible(mask, S: int, causal: bool):
+    """Which keys each query of a [bm, S, Sk] tile sees: the key mask, and
+    under ``causal`` the in-segment triangle (bus columns j >= S stay as
+    the mask sets them)."""
+    ok = mask != 0                                       # [bm, 1, Sk]
+    if not causal:
+        return ok
+    Sk = mask.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (S, Sk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (S, Sk), 1)
+    return ok & ((col >= S) | (col <= row))[None]
+
+
+def _tile_softmax(q, k, mask, scale, causal=False):
     """Masked scores + stable softmax for one [bm, S, Sk] tile; returns
     (p, l, m) with the exact arithmetic the forward uses."""
     s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32) * scale
-    s = jnp.where(mask != 0, s, NEG_INF)                 # [bm, S, Sk]
+    s = jnp.where(_visible(mask, q.shape[1], causal), s, NEG_INF)  # [bm,S,Sk]
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
     return p, l, m
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, scale: float):
-    # blocks: q [bm, S, D]; k/v [bm, Sk, D]; mask [bm, 1, Sk] int32
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, scale: float,
+                causal: bool = False):
+    # blocks: q [bm, S, D]; k [bm, Sk, D]; v [bm, Sk, Dv]; mask [bm, 1, Sk]
     q = q_ref[...].astype(jnp.float32)
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
-    p, l, _ = _tile_softmax(q, k, mask_ref[...], scale)
+    p, l, _ = _tile_softmax(q, k, mask_ref[...], scale, causal)
     o = jax.lax.dot_general(p / l, v, (((2,), (1,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32)
     o_ref[...] = o.astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, dq_ref, dk_ref,
-                dv_ref, *, scale: float):
+                dv_ref, *, scale: float, causal: bool = False):
     q = q_ref[...].astype(jnp.float32)                   # [bm, S, D]
     k = k_ref[...].astype(jnp.float32)                   # [bm, Sk, D]
     v = v_ref[...].astype(jnp.float32)
     mask = mask_ref[...]
-    do = do_ref[...].astype(jnp.float32)                 # [bm, S, D]
-    p, l, _ = _tile_softmax(q, k, mask, scale)
+    do = do_ref[...].astype(jnp.float32)                 # [bm, S, Dv]
+    p, l, _ = _tile_softmax(q, k, mask, scale, causal)
     p = p / l                                            # [bm, S, Sk]
     dv = jax.lax.dot_general(p, do, (((1,), (1,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
@@ -80,7 +101,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, dq_ref, dk_ref,
                              preferred_element_type=jnp.float32)
     delta = (p * dp).sum(axis=-1, keepdims=True)         # [bm, S, 1]
     # masked keys' scores came through jnp.where -> their ds is exactly 0
-    ds = jnp.where(mask != 0, p * (dp - delta), 0.0) * scale
+    ds = jnp.where(_visible(mask, q.shape[1], causal), p * (dp - delta),
+                   0.0) * scale
     dq = jax.lax.dot_general(ds, k, (((2,), (1,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
     dk = jax.lax.dot_general(ds, q, (((1,), (1,)), ((0,), (0,))),
@@ -90,14 +112,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, dq_ref, dk_ref,
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
+def _spec(T, D, block_m):
+    """One head of one segment for a block of news: [bm, T, D]."""
+    return pl.BlockSpec((block_m, None, None, T, D),
+                        lambda m, kk, h: (m, kk, h, 0, 0))
+
+
 def _specs(S, Sk, D, block_m):
-    q_spec = pl.BlockSpec((block_m, None, None, S, D),
-                          lambda m, kk, h: (m, kk, h, 0, 0))
-    kv_spec = pl.BlockSpec((block_m, None, None, Sk, D),
-                           lambda m, kk, h: (m, kk, h, 0, 0))
     mask_spec = pl.BlockSpec((block_m, None, 1, Sk),
                              lambda m, kk, h: (m, kk, 0, 0))
-    return q_spec, kv_spec, mask_spec
+    return _spec(S, D, block_m), _spec(Sk, D, block_m), mask_spec
 
 
 def _heads_major(x):
@@ -116,45 +140,54 @@ def _prepare(q, k, v, kv_mask, block_m):
     return ops, (M, K, S, H, D, Sk, block_m)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
-def bus_attention(q, k, v, kv_mask, *, block_m: int = 8, interpret: bool):
-    """q: [M, K, S, H, D]; k/v: [M, K, Sk, H, D]; kv_mask: [M, K, Sk] ->
-    [M, K, S, H, D]. Sk = S + K (bus columns appended by the wrapper);
-    masked (padded) keys contribute nothing. M % block_m == 0 (the ops
-    wrapper pads odd merged-set sizes up and masks the tail).
-    ``interpret`` has no default: kernels.ops decides it per backend."""
+@functools.partial(jax.jit,
+                   static_argnames=("block_m", "interpret", "causal"))
+def bus_attention(q, k, v, kv_mask, *, block_m: int = 8, interpret: bool,
+                  causal: bool = False):
+    """q: [M, K, S, H, D]; k: [M, K, Sk, H, D]; v: [M, K, Sk, H, Dv];
+    kv_mask: [M, K, Sk] -> [M, K, S, H, Dv]. Sk = S + K (bus columns
+    appended by the wrapper); masked (padded) keys contribute nothing, and
+    under ``causal`` neither do later keys of the query's own segment.
+    M % block_m == 0 (the ops wrapper pads odd merged-set sizes up and
+    masks the tail).  ``interpret`` has no default: kernels.ops decides
+    it per backend."""
     operands, (M, K, S, H, D, Sk, block_m) = _prepare(
         q, k, v, kv_mask, block_m)
-    q_spec, kv_spec, mask_spec = _specs(S, Sk, D, block_m)
-    kernel = functools.partial(_fwd_kernel, scale=D ** -0.5)
+    Dv = v.shape[-1]
+    q_spec, k_spec, mask_spec = _specs(S, Sk, D, block_m)
+    o_spec, v_spec = _spec(S, Dv, block_m), _spec(Sk, Dv, block_m)
+    kernel = functools.partial(_fwd_kernel, scale=D ** -0.5, causal=causal)
     o = pl.pallas_call(
         kernel,
         grid=(M // block_m, K, H),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((M, K, H, S, D), q.dtype),
+        in_specs=[q_spec, k_spec, v_spec, mask_spec],
+        out_specs=o_spec,
+        out_shape=jax.ShapeDtypeStruct((M, K, H, S, Dv), q.dtype),
         interpret=interpret,
     )(*operands)
     return _heads_major(o)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_m", "interpret", "causal"))
 def bus_attention_bwd(q, k, v, kv_mask, do, *, block_m: int = 8,
-                      interpret: bool):
+                      interpret: bool, causal: bool = False):
     """(dq, dk, dv) for one fused tile pass; mask gets no cotangent."""
     operands, (M, K, S, H, D, Sk, block_m) = _prepare(
         q, k, v, kv_mask, block_m)
-    q_spec, kv_spec, mask_spec = _specs(S, Sk, D, block_m)
-    kernel = functools.partial(_bwd_kernel, scale=D ** -0.5)
+    Dv = v.shape[-1]
+    q_spec, k_spec, mask_spec = _specs(S, Sk, D, block_m)
+    o_spec, v_spec = _spec(S, Dv, block_m), _spec(Sk, Dv, block_m)
+    kernel = functools.partial(_bwd_kernel, scale=D ** -0.5, causal=causal)
     dq, dk, dv = pl.pallas_call(
         kernel,
         grid=(M // block_m, K, H),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec],
-        out_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec, mask_spec, o_spec],
+        out_specs=[q_spec, k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((M, K, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((M, K, H, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((M, K, H, Sk, D), v.dtype),
+            jax.ShapeDtypeStruct((M, K, H, Sk, Dv), v.dtype),
         ],
         interpret=interpret,
     )(*operands, _heads_major(do))

@@ -128,12 +128,12 @@ def _per_data_shard(fn, mesh, n_out: int):
                          check_vma=False)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _bus_vjp(q, k, v, kv_mask, block_m, interpret):
-    return _bus_vjp_fwd(q, k, v, kv_mask, block_m, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _bus_vjp(q, k, v, kv_mask, block_m, interpret, causal):
+    return _bus_vjp_fwd(q, k, v, kv_mask, block_m, interpret, causal)[0]
 
 
-def _bus_vjp_fwd(q, k, v, kv_mask, block_m, interpret):
+def _bus_vjp_fwd(q, k, v, kv_mask, block_m, interpret, causal):
     M = q.shape[0]
     mesh = _data_mesh()
     shards = mesh.shape["data"] if mesh is not None else 1
@@ -142,18 +142,18 @@ def _bus_vjp_fwd(q, k, v, kv_mask, block_m, interpret):
         q, k, v = (_pad_rows(t, m_pad) for t in (q, k, v))
         kv_mask = _pad_rows(kv_mask, m_pad)
     fwd = functools.partial(_bus.bus_attention, block_m=block_m,
-                            interpret=interpret)
+                            interpret=interpret, causal=causal)
     o = _per_data_shard(fwd, mesh, 1)(q, k, v, kv_mask)
     return o[:M], (q, k, v, kv_mask)
 
 
-def _bus_vjp_bwd(block_m, interpret, res, do):
+def _bus_vjp_bwd(block_m, interpret, causal, res, do):
     q, k, v, kv_mask = res          # already padded to the block multiple
     m_pad = q.shape[0] - do.shape[0]
     if m_pad:
         do = _pad_rows(do, m_pad)
     bwd = functools.partial(_bus.bus_attention_bwd, block_m=block_m,
-                            interpret=interpret)
+                            interpret=interpret, causal=causal)
     dq, dk, dv = _per_data_shard(bwd, _data_mesh(), 3)(q, k, v, kv_mask, do)
     M = q.shape[0] - m_pad
     return dq[:M], dk[:M], dv[:M], None
@@ -162,8 +162,68 @@ def _bus_vjp_bwd(block_m, interpret, res, do):
 _bus_vjp.defvjp(_bus_vjp_fwd, _bus_vjp_bwd)
 
 
-def bus_attention(q, k, v, kv_mask, *, block_m: int = 8):
-    return _bus_vjp(q, k, v, kv_mask, block_m, _interpret())
+def bus_attention(q, k, v, kv_mask, *, block_m: int = 8,
+                  causal: bool = False):
+    return _bus_vjp(q, k, v, kv_mask, block_m, _interpret(), causal)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (megablox gmm forward, gmm + tgmm backward): one bf16 MXU
+# pass on every product, float32 accumulation and float32 results, the
+# backend's DEFAULT precision of a float32 matmul
+# ---------------------------------------------------------------------------
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """(tm, tk, tn) of one gmm or tgmm call, as megablox asks it for the
+    call's own dims: 128 rows; a contracted or output dim whole when it is
+    no multiple of 512 (the expert width 1,408), else in 512s -- every
+    block within the default scoped VMEM of a v5e."""
+    def tile(x):
+        return 512 if x % 512 == 0 else x
+    return 128, tile(k), tile(n)
+
+
+def _megablox():
+    """The megablox kernels' module (its package exports ``gmm`` as a
+    function of the same name)."""
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_vjp(lhs, rhs, group_sizes, interpret):
+    return _gmm_vjp_fwd(lhs, rhs, group_sizes, interpret)[0]
+
+
+def _gmm_vjp_fwd(lhs, rhs, group_sizes, interpret):
+    lb, rb = lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16)
+    out = _megablox().gmm(lb, rb, group_sizes, jnp.float32, _gmm_tiling,
+                          interpret=interpret)
+    return out, (lb, rb, group_sizes)
+
+
+def _gmm_vjp_bwd(interpret, res, g):
+    lb, rb, group_sizes = res
+    mb, gb = _megablox(), g.astype(jnp.bfloat16)
+    d_lhs = mb.gmm(gb, rb, group_sizes, jnp.float32, _gmm_tiling,
+                   transpose_rhs=True, interpret=interpret)
+    d_rhs = mb.tgmm(lb.swapaxes(0, 1), gb, group_sizes, jnp.float32,
+                    _gmm_tiling, interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_gmm_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """Rows ``lhs[offs[g]:offs[g+1]] @ rhs[g]`` for each group g, where
+    ``offs`` are the running sums of ``group_sizes``: lhs [m, k], rhs
+    [G, k, n] float32 -> [m, n] float32, gradients float32.  Rows past
+    ``sum(group_sizes)`` are not computed and hold garbage (NaN in
+    interpret mode), as do their rows of the lhs gradient; a caller masks
+    both with a select.  ``m`` is a multiple of 128."""
+    return _gmm_vjp(lhs, rhs, group_sizes, _interpret())
 
 
 # ---------------------------------------------------------------------------

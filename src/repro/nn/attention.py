@@ -62,7 +62,8 @@ def init_attention(key, cfg: AttnConfig, param_dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 def sdpa(q, k, v, *, causal: bool, mask=None, q_offset: int | None = None):
-    """q: [B,Sq,Hq,D], k/v: [B,Sk,Hkv,D]; Hq % Hkv == 0.
+    """q/k: [B,Sq|Sk,Hq|Hkv,D], v: [B,Sk,Hkv,Dv] -> [B,Sq,Hq,Dv];
+    Hq % Hkv == 0.
 
     mask: optional [B, Sk] (key validity) or [B, Sq, Sk] additive-compatible
     boolean mask. ``q_offset``: starting absolute position of q for causal
@@ -88,7 +89,7 @@ def sdpa(q, k, v, *, causal: bool, mask=None, q_offset: int | None = None):
         logits = jnp.where(m, logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", w, v)
-    return out.reshape(B, Sq, Hq, D)
+    return out.reshape(B, Sq, Hq, v.shape[-1])
 
 
 def blocked_sdpa(q, k, v, *, causal: bool, mask=None, block_q: int = 512):

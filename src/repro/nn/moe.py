@@ -1,6 +1,15 @@
 """Mixture-of-Experts substrate.
 
-Three interchangeable implementations (``impl`` knob):
+The news encoder's expert layer is ``routed_moe``: DeepSeek-V3 routing
+(sigmoid scores, a correction bias for the choice, top-k, renormalised
+and scaled gates, shared experts) as one chip of an expert-parallel
+deployment holds it -- told which experts it holds, routing over all of
+them, and computing its own experts' part of the result, dropless, with
+grouped matmuls over exactly the rows routed to them.
+
+The inherited LM family (models/lm.py) keeps the capacity-dropping
+switch-style layer below, with three interchangeable implementations
+(``impl`` knob):
   * ``dense``  — every expert runs on every token, gated by the top-k mask.
                  O(E) FLOPs; only for tiny smoke/grad tests.
   * ``gather`` — sort-based capacity dispatch on one device (the real routing
@@ -182,3 +191,126 @@ def moe_ep(p, x, cfg: MoEConfig, mesh, *, data_axes=("pod", "data"),
         out_specs=(xspec, P()),
     )(p, x)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# the news encoder's expert layer: dropless, over the experts held here
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class RoutedMoEConfig:
+    """An expert layer as one chip of an expert-parallel deployment holds
+    it.  The router scores all ``n_experts``; this chip holds the
+    ``n_held`` experts with global ids ``held_start ...`` and computes
+    their part of the result.  ``n_shared`` shared experts are one SwiGLU
+    of width ``n_shared * d_expert`` that every chip computes alike."""
+    d_model: int
+    d_expert: int
+    n_experts: int            # the router's width (published count)
+    top_k: int
+    n_held: int
+    held_start: int = 0
+    n_shared: int = 0
+    routed_scale: float = 1.0
+
+    @property
+    def d_shared(self) -> int:
+        return self.n_shared * self.d_expert
+
+
+ROW_TILE = 128      # grouped-matmul row tile; the row buffer pads to it
+
+
+def init_routed_moe(key, cfg: RoutedMoEConfig, param_dtype=jnp.float32):
+    ks = jax.random.split(key, 8)
+    d, f, g, fs = cfg.d_model, cfg.d_expert, cfg.n_held, cfg.d_shared
+    p = {"router": normal_init(ks[0], (d, cfg.n_experts), 0.02, param_dtype),
+         # noaux_tc's correction bias: it moves the choice, not the gates,
+         # and takes no gradient (a balancing rule sets it in DeepSeek-V3)
+         "router_bias": normal_init(ks[1], (cfg.n_experts,), 0.02,
+                                    param_dtype),
+         "w1": normal_init(ks[2], (g, d, f), 0.02, param_dtype),
+         "w3": normal_init(ks[3], (g, d, f), 0.02, param_dtype),
+         "w2": normal_init(ks[4], (g, f, d), 0.02, param_dtype)}
+    if cfg.n_shared:
+        p["shared"] = {"w1": normal_init(ks[5], (d, fs), 0.02, param_dtype),
+                       "w3": normal_init(ks[6], (d, fs), 0.02, param_dtype),
+                       "w2": normal_init(ks[7], (fs, d), 0.02, param_dtype)}
+    return p
+
+
+def route_sigmoid(p, x, cfg: RoutedMoEConfig):
+    """x [T, d] -> (gates [T, k] float32, experts [T, k] int32).
+
+    ``s = sigmoid(x W_r)``; the k experts of largest ``s + bias``; gates
+    ``s`` over those k, renormalised to sum 1, times ``routed_scale``.
+    The router runs at HIGHEST precision: the choice is discrete, and one
+    bf16 pass would flip near ties against a float32 reference."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", x.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32),
+                           cfg.top_k)
+    gate = jnp.take_along_axis(s, idx, axis=-1)
+    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-20)
+    return gate * cfg.routed_scale, idx
+
+
+def swiglu(w1, w3, w2, x):
+    """SwiGLU: (silu(x W1) * x W3) W2."""
+    h = jax.nn.silu(x @ w1.astype(x.dtype)) * (x @ w3.astype(x.dtype))
+    return h @ w2.astype(x.dtype)
+
+
+def _grouped(lhs, w, sizes, impl: str):
+    """[m, k] rows grouped by ``sizes`` times each group's [k, n] -> [m, n]
+    float32: the megablox kernel under "pallas", else XLA's ragged dot;
+    both at the backend's DEFAULT precision."""
+    if impl == "pallas":
+        from repro.kernels import ops as kops
+        return kops.grouped_matmul(lhs, w, sizes)
+    return jax.lax.ragged_dot(lhs, w.astype(lhs.dtype), sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def routed_moe(p, x, valid, cfg: RoutedMoEConfig, *, impl: str = "xla"):
+    """The expert layer's output on x [T, d] for the tokens ``valid`` [T]
+    marks real (padding slots are not routed), and its counts.
+
+    Every (token, chosen expert) pair whose expert is held here is a row
+    of one grouped matmul per expert weight, sorted by expert: dropless,
+    in a row buffer of T*k rows (rounded up to ``ROW_TILE``), of which
+    the kernel runs only the tiles that hold routed rows.  The rows' gated
+    outputs are summed back per token; the shared experts run on every
+    token slot.  Counts (int32): ``routed`` rows, the grouped matmul's row
+    ``slots`` run (its tiles' rows), and ``expert_rows`` [n_held]."""
+    T, d = x.shape
+    k, g = cfg.top_k, cfg.n_held
+    gate, idx = route_sigmoid(p, x, cfg)
+    local = idx - cfg.held_start
+    held = (local >= 0) & (local < g) & valid[:, None]          # [T, k]
+    group = jnp.where(held, local, g).reshape(-1)                 # [T*k]
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((g + 1,), jnp.int32).at[group].add(1)[:g]
+    n_rows = -(-T * k // ROW_TILE) * ROW_TILE
+    order = jnp.pad(order, (0, n_rows - T * k))
+    routed = jnp.arange(n_rows) < sizes.sum()
+    xs = jnp.where(routed[:, None], x[order // k], 0)
+    h = jax.nn.silu(_grouped(xs, p["w1"], sizes, impl)) \
+        * _grouped(xs, p["w3"], sizes, impl)
+    y = jnp.where(routed[:, None],
+                  _grouped(h.astype(x.dtype), p["w2"], sizes, impl), 0)
+    # back to (token, choice) order: the inverse permutation's gather
+    slot = jnp.zeros((T * k,), jnp.int32).at[order[:T * k]].set(
+        jnp.arange(T * k, dtype=jnp.int32))
+    w = jnp.where(held, gate, 0).astype(y.dtype)                  # [T, k]
+    out = jnp.einsum("tkd,tk->td", y[slot].reshape(T, k, d), w)
+    if cfg.n_shared:
+        sp = p["shared"]
+        out = out + swiglu(sp["w1"], sp["w3"], sp["w2"], x)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    tiles = jnp.where(sizes > 0, -(-ends // ROW_TILE) - starts // ROW_TILE, 0)
+    counts = {"routed": sizes.sum(), "slots": tiles.sum() * ROW_TILE,
+              "expert_rows": sizes}
+    return out.astype(x.dtype), counts

@@ -175,14 +175,26 @@ _WINDOW_KEYS = ("encoded", "encode_rows", "encode_rows_run", "enc_tokens",
                 "cache_overflow")
 
 
+# an expert encoder's per-step counts (pipeline.speedyfeed_forward's
+# ``moe_*``), summed into one ``moe_window`` count: rows routed to the
+# experts held here, grouped-matmul row slots run, and the least and most
+# rows a held expert took in each step
+_MOE_KEYS = ("routed", "slots", "rows_least", "rows_most")
+
+
 def _feed_drain_obs(host_metrics: list):
     """MetricsBuffer drain hook: fold the drained per-step cache scalars
     into obs counters and refresh the derived hit-rate gauge (plus the
     non-finite-guard skip counter, which drains on the same cadence), and
-    write the drained steps' sums as one ``train_window`` count."""
+    write the drained steps' sums as one ``train_window`` count (and one
+    ``moe_window`` count for an expert encoder)."""
     obs.counts("train_window", steps=len(host_metrics),
                **{k: int(sum(int(m[k]) for m in host_metrics))
                   for k in _WINDOW_KEYS if k in host_metrics[0]})
+    if "moe_routed" in host_metrics[0]:
+        obs.counts("moe_window", **{
+            k: int(sum(int(m[f"moe_{k}"]) for m in host_metrics))
+            for k in _MOE_KEYS})
     skipped = sum(float(m.get("nonfinite_step", 0.0)) for m in host_metrics)
     if skipped:
         obs.counter("train_nonfinite_steps_total").inc(skipped)

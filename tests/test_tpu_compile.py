@@ -105,3 +105,41 @@ def test_masked_onehot_lut_scan_compiles_for_v5e(one_chip, dim):
         l, c, v, block_n=4096, interpret=False, variant="onehot")).lower(
         lut, codes, valid).compile()
     assert _n_kernels(compiled) >= 1
+
+
+def test_causal_latent_bus_attention_grad_compiles_for_v5e(one_chip,
+                                                           monkeypatch):
+    """Moonlight-16B-A3B's latent attention through ops.bus_attention: 16
+    heads, query/key head dim 192 against value head dim 128, the causal
+    in-segment mask; forward and backward kernels both reach Mosaic."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    Mm, Hm, Dq, Dv = 32, 16, 192, 128
+    q = _sds(one_chip, (Mm, K, S, Hm, Dq))
+    k = _sds(one_chip, (Mm, K, S + K, Hm, Dq))
+    v = _sds(one_chip, (Mm, K, S + K, Hm, Dv))
+    mask = _sds(one_chip, (Mm, K, S + K), jnp.bool_)
+
+    def loss(q, k, v, mask):
+        return (ops.bus_attention(q, k, v, mask, causal=True) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v, mask).compile()
+    assert _n_kernels(compiled) >= 2
+
+
+def test_expert_grouped_matmul_grad_compiles_for_v5e(one_chip, monkeypatch):
+    """The held experts' grouped matmul at published widths (hidden 2,048,
+    expert 1,408, 8 held, a chunk's 3,072 tokens x top-6 rows): gmm
+    forward, and gmm and tgmm backward, all reach Mosaic."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    rows, d, f, g = 3072 * 6, 2048, 1408, 8
+    x = _sds(one_chip, (rows, d))
+    w = _sds(one_chip, (g, d, f))
+    sizes = _sds(one_chip, (g,), jnp.int32)
+
+    def loss(x, w, sizes):
+        return (ops.grouped_matmul(x, w, sizes) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, w, sizes).compile()
+    assert _n_kernels(compiled) >= 3
