@@ -11,9 +11,11 @@ loader's bucketing), converts batches synchronously on the step thread, and
 drains metrics with ``float(...)`` every step (blocking dispatch). No
 donation.
 
-Trainer: per-bucket warm donated executables, async device prefetch, lazy
-metrics drain. With ``--attn-impl`` taking several values, the Trainer side
-runs once per attention implementation over the SAME batch stream (same
+Trainer: one warm donated executable for every bucket (batches padded to
+the global max too, but the encode set runs each chunk at the length its
+news need), async device prefetch, lazy metrics drain. With
+``--attn-impl`` taking several values, the Trainer side runs once per
+attention implementation over the SAME batch stream (same
 loader epochs, same seeds), writing per-impl entries under
 ``by_attn_impl`` — the xla-vs-pallas comparison of the trainable fused
 kernels in the real training loop. ``attention_microbench`` additionally
@@ -51,16 +53,6 @@ from repro.configs.speedyfeed_arch import make_sf_train_step
 from repro.core import speedyfeed_state
 from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.train import make_loader, small_speedyfeed_config
-
-
-def _pad_seg(batch, seg_len):
-    """The old loop's lossy re-padding contract (kept here as the baseline)."""
-    t = batch["news_tokens"]
-    if t.shape[-1] < seg_len:
-        pad = seg_len - t.shape[-1]
-        for k in ("news_tokens", "news_freq"):
-            batch[k] = np.pad(batch[k], ((0, 0), (0, 0), (0, pad)))
-    return batch
 
 
 def _synth_batch(cfg, seg_len, seed=0):
@@ -126,7 +118,7 @@ def legacy_loop(cfg, make_batcher, *, steps, epochs, repeats):
                     raise RuntimeError(f"loader stalled at step {step}")
                 batch.pop("_stats", None)
                 batch.pop("_bucket", None)
-                batch = _pad_seg(batch, cfg.plm.seg_len)
+                batch = data.pad_segments(batch, cfg.plm.seg_len)
                 batch = {k: jnp.asarray(v) for k, v in batch.items()}
                 params, opt, cache, metrics = step_fn(
                     params, opt, cache, jnp.int32(step),
@@ -152,7 +144,7 @@ def trainer_loop(cfg, make_batcher, lcfg, *, steps, repeats, mesh=None):
     # numpy batch is placed by its in_shardings
     state = trainer.init_state(0)
     for b in lcfg.buckets:
-        wb = _synth_batch(cfg, b)
+        wb = trainer.prepare_batch(_synth_batch(cfg, b))
         if mesh is None:
             wb = jax.device_put(wb)
         state, m = trainer.step(state, wb, bucket=b)

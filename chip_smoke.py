@@ -196,14 +196,15 @@ def train_phase(cfg, device, *, steps=TRAIN_STEPS, loader_kw=None):
     log(f"[train] max |param - init| = {moved:.3e}")
     check(moved > 0.0, "parameters did not move")
 
-    # the step executable for the bucket the run used: Mosaic kernel in
-    # it, and the warm step's time (set-up information, not a benchmark)
+    # the step executable for the bucket the run used (its batch prepared
+    # as fit prepares it): Mosaic kernel in it, and the warm step's time
+    # (set-up information, not a benchmark)
     bucket = max(res.bucket_steps, key=res.bucket_steps.get)
-    batch = jax.device_put(data.synth_centralized_batch(
+    trainer = training.get_trainer("speedyfeed", cfg=cfg)
+    batch = jax.device_put(trainer.prepare_batch(data.synth_centralized_batch(
         m_cap=cfg.merged_cap, n_segments=cfg.plm.n_segments, seg_len=bucket,
         b_cap=cfg.batch_users, hist_len=cfg.hist_len, vocab=cfg.plm.vocab,
-        seed=SEED), device)
-    trainer = training.get_trainer("speedyfeed", cfg=cfg)
+        seed=SEED)), device)
     text = trainer.compiled_text(state, batch)
     n_kernels = text.count("tpu_custom_call")
     log(f"[train] compiled step (bucket {bucket}): {n_kernels} "

@@ -5,8 +5,9 @@
 Builds a synthetic Microsoft-News-like click log, then runs Algorithm 1
 (centralized encoding -> cache -> BusLM -> autoregressive loss) for 40
 steps through the unified training runtime: a registry-built Trainer with
-one warm donated executable per seg-length bucket, fed by the async
-device prefetcher.
+one warm donated executable for every seg-length bucket (each encode-set
+chunk picks its length on the device), fed by the async device
+prefetcher.
 """
 from repro import data, training
 from repro.launch.train import make_loader, small_speedyfeed_config

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import core, optim, training
+from repro import core, data, optim, training
 from repro.distributed import sharding as shx
 from repro.nn.moe import RoutedMoEConfig
 from repro.optim.adam import adam_update
@@ -131,8 +131,14 @@ def make_sf_trainer(cfg=None, **kw) -> training.Trainer:
     # mesh runs place the merged news set replicated (it feeds a global
     # argsort) and shard the user axis — the H1 layout, not generic dim-0
     kw.setdefault("batch_specs_fn", shx.speedyfeed_batch_specs)
-    return training.Trainer(cfg if cfg is not None else PROD,
-                            make_step=make_sf_train_step,
+    cfg = cfg if cfg is not None else PROD
+    if cfg.plm.decoder is None:
+        # BusLM's encode set picks each chunk's segment length on the
+        # device (core.pipeline.encode_set), so every bucket runs padded to
+        # seg_len in one program; an expert encoder keeps one per bucket
+        kw.setdefault("prepare_batch", functools.partial(
+            data.pad_segments, seg_len=cfg.plm.seg_len))
+    return training.Trainer(cfg, make_step=make_sf_train_step,
                             init_fn=_sf_init_state, **kw)
 
 

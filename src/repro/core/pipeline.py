@@ -4,8 +4,9 @@ One training step over a centralized batch:
   1. merged news set M (deduplicated by the loader or by gather_dedup)
   2. cache plan: which news reuse cached embeddings, which get encoded
      (fixed budget E; p_t scheduler; gamma expiry)                  §4.1.2
-  3. BusLM-encode the encode set, chunk by chunk, skipping the
-     chunks that hold no news to encode                             §4.1.3
+  3. BusLM-encode the encode set, chunk by chunk, each chunk at the
+     segment length its news need, skipping the chunks that hold no
+     news to encode                                                 §4.1.3
   4. assemble + dispatch embeddings to history positions            §4.1.1
   5. autoregressive user modeling + Eq.5 loss over all L positions  §4.1.4
   6. refresh cache
@@ -16,6 +17,7 @@ dedup/cache/AR) used as the speedup baseline in benchmarks (paper Table 4).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple
 
 import jax
@@ -83,6 +85,12 @@ def encode_chunk_rows(n_rows: int) -> int:
     return n_rows // 16 if n_rows % 128 == 0 else n_rows
 
 
+def seg_len_classes(S: int) -> tuple:
+    """The segment lengths an encode-set chunk of ``S``-token segments may
+    run at: the multiples of 8 below ``S``, and ``S``."""
+    return tuple(range(8, S, 8)) + (S,)
+
+
 def encode_set(plm_params, cfg: SpeedyFeedConfig, tokens, freq, n_valid):
     """BusLM-encode the encode set's ``n_valid`` rows that need encoding.
 
@@ -90,48 +98,117 @@ def encode_set(plm_params, cfg: SpeedyFeedConfig, tokens, freq, n_valid):
     ``n_valid`` of the E rows.  The set runs in chunks of
     ``encode_chunk_rows(E)`` rows, and a chunk that holds none of them is
     not run: its rows are zeros, which neither ``assemble_embeddings`` nor
-    ``cache_refresh`` reads (both select by ``enc_valid``).  A scan over
-    the chunks with a cond in its body keeps one copy of the encoder,
-    forward and backward, in the program, and only the taken branch runs.
-    A single chunk always runs.  An expert encoder's set runs in the same
-    chunks with a backward pass of its own
+    ``cache_refresh`` reads (both select by ``enc_valid``).  A single chunk
+    always runs.
+
+    A chunk runs at the shortest of ``seg_len_classes(S)`` that holds its
+    must-encode rows' tokens (a row's length is its last real token's
+    position + 1, over its segments): the tokens past it are padding.  So
+    that the short rows share chunks, the must-encode rows are sorted by
+    length first (the rows behind them stay where they are), and the
+    embeddings are put back in the plan's order.  The chunks run in a
+    scan with a switch over the lengths in its body, and the backward pass
+    is written out (``_encode_chunks``).  An expert encoder's set runs in
+    the same chunks, untrimmed, with a backward pass of its own
     (``core.buslm.decoder_encode_set``).  Returns ([E, news_dim]
-    embeddings, the number of rows the encoder ran, and the expert
-    encoder's counts -- none for BusLM).
+    embeddings, the number of rows the encoder ran, and counts: the
+    expert encoder's, or BusLM's token slots run (rows x K x length) and
+    chunks run below S).
     """
-    from repro.distributed import sharding as shx
-
-    def encode(p, t, f):
-        # the merged set is replicated (global dedup/argsort); the encode
-        # set is data-sharded so the PLM runs data-parallel -- without
-        # this constraint XLA keeps the whole encoder replicated
-        return buslm_encode(p, cfg.plm, shx.constrain(t, "encode_batch"),
-                            shx.constrain(f, "encode_batch"),
-                            impl=cfg.attn_impl)
-
-    E = tokens.shape[0]
+    E, K, S = tokens.shape
     G = encode_chunk_rows(E)
+    C = E // G
     if cfg.plm.decoder is not None:
         emb, counts = decoder_encode_set(plm_params, cfg.plm, tokens,
                                          n_valid, G)
-        return emb, jnp.minimum(-(-n_valid // G), E // G) * G, counts
-    if G == E:
-        return encode(plm_params, tokens, freq), jnp.int32(E), {}
-    C = E // G
-    chunks = (jnp.arange(C), tokens.reshape(C, G, *tokens.shape[1:]),
-              freq.reshape(C, G, *freq.shape[1:]))
-    out = jax.eval_shape(encode, plm_params, chunks[1][0], chunks[2][0])
+        return emb, jnp.minimum(-(-n_valid // G), C) * G, counts
+    classes = jnp.asarray(seg_len_classes(S))
+    live = jnp.arange(E) < n_valid
+    length = jnp.where(live, jnp.max(jnp.where(
+        tokens != 0, jnp.arange(1, S + 1), 0), axis=(1, 2)), 0)
+    order = jnp.argsort(jnp.where(live, length, S + 1), stable=True)
+    which = jnp.searchsorted(classes, length[order].reshape(C, G).max(1))
+    ran = (jnp.arange(C) * G < n_valid) | (C == 1)
+    emb = _encode_chunks(cfg, plm_params, jnp.where(ran, which + 1, 0),
+                         tokens[order].reshape(C, G, K, S),
+                         freq[order].reshape(C, G, K, S))
+    s_run = classes[which]
+    return (emb.reshape(E, -1)[jnp.argsort(order)], jnp.sum(ran) * G,
+            {"enc_token_slots_run": jnp.sum(ran * G * K * s_run),
+             "encode_chunks_short": jnp.sum(ran & (s_run < S))})
+
+
+def _chunk_encoders(cfg: SpeedyFeedConfig, S: int) -> list:
+    """``(params, tokens, freq) -> [G, news_dim]`` for a chunk of
+    ``S``-token segments, one for each of ``seg_len_classes(S)``, each
+    encoding the tokens up to its length."""
+    from repro.distributed import sharding as shx
+    # the whole chunk is recomputed for its backward pass, so its layers
+    # are not recomputed again
+    plm = dataclasses.replace(cfg.plm, remat=False)
+
+    def encode(p, t, f, s):
+        # the merged set is replicated (global dedup/argsort); the encode
+        # set is data-sharded so the PLM runs data-parallel -- without
+        # this constraint XLA keeps the whole encoder replicated
+        return buslm_encode(p, plm,
+                            shx.constrain(t[:, :, :s], "encode_batch"),
+                            shx.constrain(f[:, :, :s], "encode_batch"),
+                            impl=cfg.attn_impl)
+
+    return [functools.partial(encode, s=s) for s in seg_len_classes(S)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _encode_chunks(cfg, params, branch, tokens, freq):
+    """[C, G, news_dim]: each chunk of ``tokens``/``freq`` [C, G, K, S]
+    encoded at length ``seg_len_classes(S)[branch - 1]``, or zeros where
+    ``branch`` is 0.  Autodiff of the switch would keep every chunk's
+    activations, one set per length and in each length's own layout, for
+    the backward pass (a v5e cannot hold them for the production encode
+    set); the backward pass instead encodes each chunk again and pulls
+    its gradient back at once, into one accumulator."""
+    return _encode_chunks_fwd(cfg, params, branch, tokens, freq)[0]
+
+
+def _encode_chunks_fwd(cfg, params, branch, tokens, freq):
+    runs = _chunk_encoders(cfg, tokens.shape[-1])
+    out = jax.eval_shape(runs[0], params, tokens[0], freq[0])
 
     def skip(p, t, f):
         return jnp.zeros(out.shape, out.dtype)
 
     def body(carry, xs):
-        c, t, f = xs
-        return carry, jax.lax.cond(c * G < n_valid, encode, skip,
-                                   plm_params, t, f)
+        b, t, f = xs
+        return carry, jax.lax.switch(b, [skip, *runs], params, t, f)
 
-    _, emb = jax.lax.scan(body, None, chunks)
-    return emb.reshape(E, -1), jnp.minimum(-(-n_valid // G), C) * G, {}
+    _, emb = jax.lax.scan(body, None, (branch, tokens, freq))
+    return emb, (params, branch, tokens, freq)
+
+
+def _encode_chunks_bwd(cfg, res, d_emb):
+    params, branch, tokens, freq = res
+
+    def pull(run):
+        def add_grad(acc, t, f, d):
+            _, vjp = jax.vjp(lambda p: run(p, t, f), params)
+            return jax.tree.map(jnp.add, acc, vjp(d)[0])
+        return add_grad
+
+    grads = [lambda acc, t, f, d: acc] + [
+        pull(run) for run in _chunk_encoders(cfg, tokens.shape[-1])]
+
+    def body(acc, xs):
+        b, t, f, d = xs
+        return jax.lax.switch(b, grads, acc, t, f, d), None
+
+    with jax.named_scope("plm_encode"):
+        acc, _ = jax.lax.scan(body, jax.tree.map(jnp.zeros_like, params),
+                              (branch, tokens, freq, d_emb))
+    return acc, None, None, None
+
+
+_encode_chunks.defvjp(_encode_chunks_fwd, _encode_chunks_bwd)
 
 
 class StepOut(NamedTuple):
@@ -152,7 +229,7 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
     # Each stage runs under a named scope (plm_encode, cache, user_model,
     # loss; the optimizer adds update), so every device op of the step
     # carries its stage in its name stack -- through autodiff
-    # (``transpose(jvp(plm_encode))``) and remat (``checkpoint``) too.
+    # (``transpose(jvp(plm_encode))``) and the encode set's recompute too.
 
     # (2) cache plan + (3) encode the budget set
     with jax.named_scope("cache"):
@@ -205,7 +282,7 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
             "enc_token_slots": encoded * (K * S),
             "merged_news": (news_ids != 0).sum(),
         })
-        if enc_counts:
+        if "expert_rows" in enc_counts:
             # an expert encoder's rows routed to the experts held here,
             # the grouped matmuls' row slots run, and the least and most
             # rows any held expert took, over the step's layers and chunks
@@ -214,6 +291,10 @@ def speedyfeed_forward(params, cfg: SpeedyFeedConfig, batch, cache: CacheState,
                       "moe_slots": enc_counts["slots"],
                       "moe_rows_least": rows.min(),
                       "moe_rows_most": rows.max()})
+        else:
+            # the token slots of the chunks the encoder ran, each at the
+            # segment length it chose, and the chunks run below S
+            m.update(enc_counts)
     return StepOut(loss, new_cache, m)
 
 

@@ -98,6 +98,17 @@ def default_buckets(seg_len: int, base: tuple | None = None) -> tuple:
                          for b in base} | {int(seg_len)}))
 
 
+def pad_segments(batch: dict, seg_len: int) -> dict:
+    """A centralized batch with ``news_tokens`` and ``news_freq`` padded
+    with zeros to ``seg_len`` tokens a segment (host arrays)."""
+    out = dict(batch)
+    for k in ("news_tokens", "news_freq"):
+        x = np.asarray(out[k])
+        out[k] = np.pad(x, [(0, 0)] * (x.ndim - 1)
+                        + [(0, seg_len - x.shape[-1])])
+    return out
+
+
 def synth_centralized_batch(*, m_cap: int, n_segments: int, seg_len: int,
                             b_cap: int, hist_len: int, vocab: int,
                             seed: int = 0) -> dict:

@@ -5,14 +5,15 @@ Why this subsystem exists (paper §4.2.2 + ROADMAP "fast as the hardware
 allows"): SpeedyFeed's throughput claim is only realized when the loop
 around the encoder never stalls the accelerator. Three design points:
 
-**Per-bucket warm executables.** The dynamic batcher emits batches whose
-news tokens are padded only to their seg-length *bucket* (8/16/24/32...),
-not the global max. One ``jax.jit`` state step serves all buckets: jit's
-shape-keyed executable cache compiles each bucket once and reuses it warm
-thereafter, so a short-segment batch genuinely runs a short program —
-N steps over K buckets must cost exactly K compilations (tested). On TPU a
-bucket is a distinct static shape, which is precisely how the paper's
-fully-dynamic batch sizes map onto XLA's static-shape world.
+**Warm executables, one per batch shape.** The dynamic batcher emits
+batches whose news tokens are padded only to their seg-length *bucket*
+(8/16/24/32...), not the global max. One ``jax.jit`` state step serves all
+buckets: jit's shape-keyed executable cache compiles each shape once and
+reuses it warm thereafter.  SpeedyFeed's BusLM step picks each encode-set
+chunk's segment length on the device, so its Trainer pads every batch to
+the global max on the host (``prepare_batch``) and N steps over K buckets
+cost one compilation; an expert encoder keeps a program per bucket, K
+compilations (both tested).
 
 **Donated TrainState.** Params, optimizer moments, the news-embedding cache
 (O(n_news * news_dim) — by far the largest train-state tensor at production

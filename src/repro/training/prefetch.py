@@ -49,7 +49,7 @@ class DevicePrefetcher:
 
     def __init__(self, make_batcher, *, depth: int = 2,
                  max_epochs: int | None = None, device=None,
-                 poll: float = 0.25, sharding=None):
+                 poll: float = 0.25, sharding=None, prepare=None):
         self._make = make_batcher
         self._depth = depth
         self._max_epochs = max_epochs
@@ -60,6 +60,9 @@ class DevicePrefetcher:
         # sharding builder) — batches land committed to their final layout,
         # so the step jit never reshards input
         self._sharding = sharding
+        # host batch -> host batch before the copy (the Trainer's
+        # ``prepare_batch``)
+        self._prepare = prepare
         self._q = queue.Queue(maxsize=max(1, depth))
         self._stop = threading.Event()
         self._finished = threading.Event()
@@ -98,6 +101,8 @@ class DevicePrefetcher:
                 stats = item.pop("_stats", None)
                 bucket = int(item.pop("_bucket",
                                       (stats or {}).get("seg_len", 0)))
+                if self._prepare is not None:
+                    item = self._prepare(item)
                 faults.fire("prefetch.h2d", step=epoch)
                 with obs.span("prefetch_h2d"):
                     if self._sharding is not None:

@@ -1,11 +1,13 @@
 """Trainer — bucket-aware donated step executables + async input pipeline.
 
 One `jax.jit`-wrapped state step with `donate_argnums=(0,)` serves every
-seg-length bucket: jit's shape-keyed cache gives each bucket its own warm
-executable, so a bucket-8 batch runs a bucket-8 program instead of being
-padded up to the global max (which silently threw away the loader's
-bucketing). Compilations are observed via a `jax.monitoring` hook and
-accounted per bucket — recompile hygiene is a tested invariant, not a hope.
+seg-length bucket: jit's shape-keyed cache gives each bucket shape its own
+warm executable, so a bucket-8 batch runs a bucket-8 program.  A step
+that picks its own lengths on the device (SpeedyFeed's BusLM encode set)
+instead has every batch padded to one shape on the host
+(``prepare_batch``), and so one executable for every bucket.
+Compilations are observed via a `jax.monitoring` hook and accounted per
+bucket — recompile hygiene is a tested invariant, not a hope.
 
 The step path never syncs: batches arrive device-resident from the
 DevicePrefetcher, metrics stay device scalars in a MetricsBuffer and are
@@ -171,7 +173,8 @@ _CACHE_COUNTER_KEYS = (
 # a profiler trace is being captured, an event on the trace's clock whose
 # stats a benchmark window sums
 _WINDOW_KEYS = ("encoded", "encode_rows", "encode_rows_run", "enc_tokens",
-                "enc_token_slots", "cache_hits", "merged_news",
+                "enc_token_slots", "enc_token_slots_run",
+                "encode_chunks_short", "cache_hits", "merged_news",
                 "cache_overflow")
 
 
@@ -255,10 +258,14 @@ class Trainer:
     """
 
     def __init__(self, cfg, *, make_step, init_fn, donate: bool = True,
-                 mesh=None, batch_specs_fn=None, nonfinite_guard: bool = True):
+                 mesh=None, batch_specs_fn=None, nonfinite_guard: bool = True,
+                 prepare_batch=None):
         self.cfg = cfg
         self._raw_step = make_step(cfg)
         self._init_fn = init_fn
+        # host batch -> host batch, applied by fit's prefetcher before the
+        # H2D copy (e.g. padding every bucket to one shape)
+        self.prepare_batch = prepare_batch or (lambda batch: batch)
         self._donate = donate
         # nonfinite_guard: when the raw step's loss comes back NaN/Inf the
         # params / optimizer moments / cache keep their pre-step values (a
@@ -475,7 +482,7 @@ class Trainer:
         prefetcher = DevicePrefetcher(
             lambda e: make_batcher(e + epoch0), depth=prefetch_depth,
             sharding=self.batch_shardings if self.mesh is not None
-            else None).start()
+            else None, prepare=self.prepare_batch).start()
         n_hosts = hosts if hosts is not None else jax.process_count()
         monitor = StepTimeMonitor(n_hosts=max(n_hosts, 1))
         buf = MetricsBuffer(on_drain=_feed_drain_obs)

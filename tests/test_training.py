@@ -129,27 +129,46 @@ def test_make_loader_uses_config_buckets():
 # recompile hygiene + donation
 # ---------------------------------------------------------------------------
 
-def test_k_buckets_compile_exactly_k_executables():
-    cfg = tiny_cfg()
+def expert_cfg():
+    """``tiny_cfg`` with a small expert (DeepSeek-V3) news encoder."""
+    from repro.nn.moe import RoutedMoEConfig
+    moe = RoutedMoEConfig(d_model=32, d_expert=16, n_experts=8, top_k=2,
+                          n_held=4, n_shared=1)
+    dec = core.DecoderConfig(kv_lora_rank=16, qk_nope_head_dim=8,
+                             qk_rope_head_dim=8, v_head_dim=8, n_dense=1,
+                             moe=moe)
+    return tiny_cfg(n_layers=2, decoder=dec, use_freq=False)
+
+
+@pytest.mark.parametrize("encoder,executables", [
+    ("buslm", 1),     # the encode set picks its lengths: one program
+    ("expert", 2),    # one program per bucket
+])
+def test_k_buckets_compile_exactly_k_executables(encoder, executables):
+    cfg = tiny_cfg() if encoder == "buslm" else expert_cfg()
     trainer = training.get_trainer("speedyfeed", cfg=cfg)
     state = trainer.init_state(seed=0)
     buckets = (8, 16)
-    # N steps over K buckets -> exactly K compilations
+
+    def batch(b, seed):
+        return jax.device_put(trainer.prepare_batch(synth_batch(cfg, b,
+                                                                seed=seed)))
+
+    # N steps over K buckets -> the expected number of compilations
     for i in range(6):
         b = buckets[i % 2]
-        batch = jax.device_put(synth_batch(cfg, b, seed=i))
-        state, metrics = trainer.step(state, batch, bucket=b)
-    assert trainer.executable_count() == len(buckets)
+        state, metrics = trainer.step(state, batch(b, i), bucket=b)
+    assert trainer.executable_count() == executables
     assert set(trainer.compile_counts) == set(buckets)
-    assert all(c >= 1 for c in trainer.compile_counts.values())
+    assert sum(c >= 1 for c in trainer.compile_counts.values()) \
+        == executables
     # warm buckets never recompile
     with training.CompileCounter() as cc:
         for i in range(4):
             b = buckets[i % 2]
-            batch = jax.device_put(synth_batch(cfg, b, seed=10 + i))
-            state, metrics = trainer.step(state, batch, bucket=b)
+            state, metrics = trainer.step(state, batch(b, 10 + i), bucket=b)
     assert cc.count == 0
-    assert trainer.executable_count() == len(buckets)
+    assert trainer.executable_count() == executables
     assert trainer.warm_compiles == {}
     assert np.isfinite(float(jax.device_get(metrics["loss"])))
 
@@ -392,7 +411,7 @@ def test_traced_fit_writes_step_spans_and_window_counts(tmp_path):
     assert [st["steps"] for st in window] == [3, 3, 1]
     hist = trainer.metrics_buffer.history
     for key in ("encoded", "encode_rows_run", "cache_hits", "enc_tokens",
-                "merged_news"):
+                "enc_token_slots_run", "encode_chunks_short", "merged_news"):
         assert sum(st[key] for st in window) == sum(hist[key])
     assert sum(hist["cache_hits"]) > 0
     E, K = cfg.cache.encode_budget, cfg.plm.n_segments
@@ -402,9 +421,14 @@ def test_traced_fit_writes_step_spans_and_window_counts(tmp_path):
         assert st["encoded"] <= st["encode_rows_run"] < st["steps"] * E
     assert 0 < sum(st["enc_tokens"] for st in window) \
         <= sum(st["enc_token_slots"] for st in window)
-    slots = sum(e * K * b for e, b in zip(
-        hist["encoded"], [int(st["bucket"]) for st in steps]))
-    assert sum(st["enc_token_slots"] for st in window) == slots
+    # every bucket runs padded to seg_len; each chunk of the encode set
+    # runs at the length its rows need, at most seg_len
+    S = cfg.plm.seg_len
+    assert sum(st["enc_token_slots"] for st in window) \
+        == sum(hist["encoded"]) * K * S
+    assert sum(st["enc_tokens"] for st in window) \
+        <= sum(st["enc_token_slots_run"] for st in window) \
+        <= sum(st["encode_rows_run"] for st in window) * K * S
     assert obs.counter("train_window_steps_total").value == 7
     assert obs.histogram("span_ms", name="train_step",
                          bucket=str(steps[0]["bucket"])).count >= 1
@@ -448,13 +472,16 @@ def _step_text_and_scopes(cfg):
 @pytest.mark.parametrize("remat,attn_impl", [(False, "xla"), (True, "xla"),
                                              (True, "pallas")])
 def test_step_ops_carry_program_scopes(remat, attn_impl):
-    """Forward, remat recompute and backward -- the bus kernel's custom
-    VJP included -- keep the stage's scope in every op's name stack."""
+    """Forward, recompute and backward -- the bus kernel's custom VJP
+    included -- keep the stage's scope in every op's name stack.  The
+    encode set encodes each chunk again for its backward pass whether or
+    not the config asks for remat, so the recompute is there either way."""
+    import re
     text, seen = _step_text_and_scopes(tiny_cfg(remat=remat,
                                                  attn_impl=attn_impl))
     assert {"plm_encode", "user_model", "loss"} <= seen
-    if remat:
-        assert "checkpoint" in text
+    assert re.search(r'op_name="[^"]*transpose\(jvp\(plm_encode\)\)'
+                     r'/plm_encode/[^"]*/jvp\(', text)
 
 
 def test_chunked_encode_step_keeps_scopes():
